@@ -41,6 +41,7 @@ import numpy as np
 import repro
 from repro.core import (
     CheckpointSpec,
+    DeviceFailure,
     DurableWorkQueue,
     ExecutionPolicy,
     FailurePlan,
@@ -162,7 +163,7 @@ def measure(*, scale: int = 14, every_k: int = 8, repeats: int = 3,
         try:
             run_program(sem, prog, max_supersteps=60, checkpoint=spec,
                         _plan=FailurePlan({kill_at: "crash"}))
-        except Exception:
+        except DeviceFailure:
             pass  # the injected crash
         _, t_recover = timeit(
             lambda: run_program(sem, prog, max_supersteps=60,

@@ -40,6 +40,7 @@ import time
 import traceback
 
 from .common import print_rows, row
+from .compile_cache import use_compile_cache
 
 BENCHES = [
     "bench_api",
@@ -428,6 +429,7 @@ def main() -> int:
         help="also write rows (and claim verdicts) as JSON",
     )
     args = ap.parse_args()
+    print(f"# compile cache: {use_compile_cache()}", flush=True)
     if args.smoke:
         if args.only or args.full:
             print("# --smoke ignores --only/--full", flush=True)

@@ -27,16 +27,10 @@ Three capabilities the rules in :mod:`repro.analysis.rules` share:
 """
 from __future__ import annotations
 
+import sysconfig
 from typing import Iterator, List, Optional, Sequence, Tuple
 
-import jax
-
-try:  # the frame API lives in jax._src on this JAX; fail soft if it moves
-    from jax._src import source_info_util as _siu
-except ImportError:  # pragma: no cover - newer jax relocations
-    _siu = None
-
-_core = jax.core
+from jax.extend import core as _core
 
 __all__ = [
     "eqn_location",
@@ -52,34 +46,20 @@ __all__ = [
 _ENGINE_PARTS = ("repro/core/", "repro/kernels/", "repro\\core\\",
                  "repro\\kernels\\")
 _NOISE_PARTS = ("repro/analysis/", "repro\\analysis\\", "/jax/", "\\jax\\",
-                "jax/_src", "site-packages")
+                "jax/_src", "site-packages", sysconfig.get_paths()["stdlib"])
 
 
 def frame_is_engine(file_name: str) -> bool:
     return any(p in file_name for p in _ENGINE_PARTS)
 
 
-def _frames(source_info):
-    if _siu is None:
-        return []
-    try:
-        return list(_siu.user_frames(source_info))
-    except Exception:  # pragma: no cover - alternate jax frame APIs
-        f = getattr(source_info, "traceback", None)
-        return [] if f is None else []
-
-
 def user_location(eqn) -> Optional[Tuple[str, int, str]]:
     """``(file, line, function)`` of the eqn's innermost user frame, or
     None when the trace carries no usable frame (e.g. synthesized eqns)."""
-    for fr in _frames(eqn.source_info):
-        fname = getattr(fr, "file_name", "")
-        if any(p in fname for p in _NOISE_PARTS):
-            continue
-        line = getattr(fr, "start_line", None)
-        if line is None:  # pragma: no cover - older Frame layout
-            line = getattr(fr, "line_num", 0)
-        return fname, int(line), getattr(fr, "function_name", "")
+    tb = eqn.source_info.traceback
+    for fr in (tb.frames if tb is not None else ()):  # innermost first
+        if not any(p in fr.file_name for p in _NOISE_PARTS):
+            return fr.file_name, int(fr.line_num), fr.function_name
     return None
 
 

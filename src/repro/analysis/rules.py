@@ -59,6 +59,7 @@ from typing import Any, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Literal
 import numpy as np
 
 from ..core.engine import ExecutionPolicy
@@ -323,7 +324,7 @@ def _rule_r5_semiring(prog, sg, x_dtype) -> List[Finding]:
 def _rule_r6_converged(closed, hook_loc: str) -> List[Finding]:
     jx = closed.jaxpr
     flat_out = jx.outvars
-    if all(isinstance(v, jax.core.Literal) for v in flat_out):
+    if all(isinstance(v, Literal) for v in flat_out):
         val = flat_out[0].val if flat_out else None
         return [_finding(
             "R6", f"converged() is the constant {val!r}: the loop "

@@ -545,7 +545,7 @@ def blocked_backend_spmv(
     weighted graphs, where real weights baked into the matmul mass could
     drop a zero/negative-weight edge from the y>0 reachability threshold).
     """
-    from ..kernels.spmv import blocked_spmv, default_interpret, tile_byte_size
+    from ..kernels.spmv import blocked_spmv, tile_byte_size
 
     bg, active_on, deg = _select_blocked(sg, direction, reverse)
     if bg is None:
@@ -553,8 +553,6 @@ def blocked_backend_spmv(
             "SemGraph has no blocked views; build with "
             "device_graph(..., blocked=True)"
         )
-    if interpret is None:
-        interpret = default_interpret()
 
     boolean = _check_blocked_semiring(sr, bg.semiring, sg.w is not None)
 

@@ -74,6 +74,13 @@ __all__ = [
 State = Any
 
 
+def under_trace(*args) -> bool:
+    """True inside a JAX transformation: an argument leaf is a tracer, or
+    a fresh array is one (``jit`` traces even a closure's constants)."""
+    return any(isinstance(leaf, jax.core.Tracer)
+               for leaf in jax.tree_util.tree_leaves((args, jnp.zeros(()))))
+
+
 class Frontier(NamedTuple):
     """One superstep's logical multicast: ``active`` vertices send ``x``.
 
@@ -284,11 +291,7 @@ def run_program(
 
         return run_program_host(sg, prog, pol, seeds=seeds,
                                 max_supersteps=max_supersteps)
-    try:
-        eager = jax.core.trace_state_clean()
-    except AttributeError:  # future jax: assume traced, keep inline loop
-        eager = False
-    if eager:
+    if not under_trace(sg, seeds):
         # Eager device runs ride the checkpointed driver with
         # checkpointing off: the SAME while-loop body, traced once and
         # cached across calls (recovery._SEG_CACHE), so repeated runs
@@ -450,15 +453,12 @@ def run_program_batched(
     was retired mid-run, ``None`` otherwise (values are reassembled from
     per-part ``finalize`` calls).
     """
-    try:
-        if not jax.core.trace_state_clean():
-            raise ValueError(
-                "run_program_batched cannot run under jit: column "
-                "retirement and per-query bookkeeping need concrete "
-                "convergence masks each superstep"
-            )
-    except AttributeError:
-        pass
+    if under_trace(sg, seeds):
+        raise ValueError(
+            "run_program_batched cannot run under jit: column "
+            "retirement and per-query bookkeeping need concrete "
+            "convergence masks each superstep"
+        )
     pol = policy if policy is not None else prog.default_policy
     pol = pol if pol is not None else ExecutionPolicy()
     is_host = pol.residency == "host" or getattr(sg, "is_host_view", False)

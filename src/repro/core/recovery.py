@@ -56,6 +56,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import jaxpr_as_fun
 import numpy as np
 
 from ..checkpoint import CheckpointManager, latest_step, load_extra
@@ -394,7 +395,7 @@ def _build_segment_fn(sg, prog, pol):
         hit = cache.get(sig)
         if hit is None:
             jaxpr, out_shape = jax.make_jaxpr(seg, return_shape=True)(*args)
-            hit = (jax.core.jaxpr_as_fun(jaxpr),
+            hit = (jaxpr_as_fun(jaxpr),
                    jax.tree_util.tree_structure(out_shape))
             cache[sig] = hit
         run_jaxpr, out_tree = hit

@@ -68,6 +68,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import jaxpr_as_fun
 import numpy as np
 
 from ..graph.csr import Graph
@@ -209,7 +210,7 @@ def _loopify(fn):
         hit = cache.get(sig)
         if hit is None:
             jaxpr, out_shape = jax.make_jaxpr(run, return_shape=True)(*args)
-            hit = (jax.core.jaxpr_as_fun(jaxpr),
+            hit = (jaxpr_as_fun(jaxpr),
                    jax.tree_util.tree_structure(out_shape))
             cache[sig] = hit
         run_jaxpr, out_tree = hit

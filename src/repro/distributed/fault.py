@@ -228,13 +228,26 @@ def supervise_workers(
     every task to fail ``max_attempts`` times); ``timeout`` bounds the
     whole run — on expiry the pool is terminated and the report says
     ``finished=False`` rather than hanging a test suite forever.
+
+    A TPU belongs to one process at a time, so a parent on the TPU
+    backend refuses to start workers (each would import JAX and wait for
+    the chip it holds); run the pool under ``JAX_PLATFORMS=cpu``.
     """
     import multiprocessing as mp
+
+    import jax
 
     from ..core.workqueue import DurableWorkQueue, _durable_worker_main
 
     if not isinstance(queue, DurableWorkQueue):
         raise TypeError("supervise_workers needs a DurableWorkQueue")
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "supervise_workers would start OS worker processes that import "
+            "JAX while this process holds the TPU; a chip serves one "
+            "process, so the workers would fail or hang.  Run the worker "
+            "pool with JAX_PLATFORMS=cpu, or run the sweep in-process."
+        )
     ctx = mp.get_context("spawn")
     cfg = {
         "lease_timeout": queue.lease_timeout,

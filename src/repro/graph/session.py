@@ -40,7 +40,7 @@ from ..core import (
     run_program,
     run_program_batched,
 )
-from ..core.program import VertexProgram
+from ..core.program import VertexProgram, under_trace
 from ..core.sem import _store_record_bytes, device_graph
 from ..core.semiring import PLUS_TIMES
 # Algorithm imports are eager: a lazy import executed during a user's first
@@ -62,14 +62,6 @@ from . import csr
 __all__ = ["Graph"]
 
 _BLOCKED = ("blocked", "blocked_compact")
-
-
-def _eager() -> bool:
-    """True outside any jit trace (the batched driver is eager-only)."""
-    try:
-        return jax.core.trace_state_clean()
-    except AttributeError:  # pragma: no cover - older/newer jax layouts
-        return True
 
 
 def _i32(value) -> jnp.ndarray:
@@ -475,10 +467,11 @@ class Graph:
         IOStats field divided by ``K`` is the per-query amortized cost.
         Values are bitwise-identical to K independent runs either way.
         """
-        scalar = jnp.ndim(sources) == 0
+        scalar = np.ndim(sources) == 0
         seeds = jnp.atleast_1d(jnp.asarray(sources, jnp.int32))
         prog = BFSProgram()
-        driver = run_program if (scalar or not _eager()) else run_program_batched
+        driver = (run_program if scalar or under_trace(seeds)
+                  else run_program_batched)
         res = driver(self._sem(policy, prog), prog, policy, seeds=seeds,
                      max_supersteps=max_supersteps,
                      checkpoint=checkpoint, resume=resume)
@@ -522,7 +515,7 @@ class Graph:
             seeds = jnp.asarray(reset)
             if seeds.ndim == 0:
                 seeds = seeds[None]
-            driver = run_program_batched if _eager() else run_program
+            driver = run_program if under_trace(seeds) else run_program_batched
             return driver(self._sem(policy, prog), prog, policy, seeds=seeds,
                           max_supersteps=max_iters,
                           checkpoint=checkpoint, resume=resume)

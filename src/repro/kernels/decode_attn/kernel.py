@@ -34,8 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..pallas_compat import tpu_compiler_params
-
 __all__ = ["decode_attn_pallas"]
 
 NEG_INF = -2.0e38
@@ -147,7 +145,7 @@ def decode_attn_pallas(
         functools.partial(_kernel, window=window, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

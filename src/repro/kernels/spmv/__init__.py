@@ -11,11 +11,13 @@ from .ops import (
     tile_byte_size,
     x_fetch_count,
 )
+from .kernel import SMEM_TILE_CAP
 from .order import curve_bits, hilbert_key, morton_key
 from .ref import blocked_spmv_ref
 
 __all__ = [
     "BlockedGraph",
+    "SMEM_TILE_CAP",
     "TILE_ORDERS",
     "blocked_spmv",
     "build_blocked",

@@ -76,11 +76,29 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..pallas_compat import tpu_compiler_params
-
-__all__ = ["spmv_pallas", "spmv_pallas_compact"]
+__all__ = ["SMEM_TILE_CAP", "spmv_pallas", "spmv_pallas_compact"]
 
 _NEG = -3.0e38
+
+# Both grids scalar-prefetch six int32[grid] schedule arrays into SMEM (the
+# compact grid's seventh, ``nact``, is one element), and SMEM is 1 MiB.
+# The largest grid the v5e compiler accepts is 43,008 steps (6 x 4 B x
+# 43,008 = 1008 KiB); 43,009 fails with "Ran out of memory in memory space
+# smem".  tests/test_tpu_compile.py pins both sides.
+SMEM_TILE_CAP = 43_008
+
+
+def _check_grid_fits(steps: int) -> None:
+    """Refuse a compiled grid whose schedule would overflow SMEM, before
+    Mosaic does so with an opaque out-of-memory error."""
+    if steps > SMEM_TILE_CAP:
+        raise ValueError(
+            f"blocked SpMV grid of {steps} tiles exceeds the compiled "
+            f"kernel's SMEM tile cap of {SMEM_TILE_CAP}: it scalar-prefetches "
+            "six int32[tiles] schedule arrays into the TPU's 1 MiB SMEM.  "
+            "Use backend='scan' or 'compact' for this graph (splitting the "
+            "grid is not implemented)."
+        )
 
 
 def _kernel_plus_times(
@@ -158,6 +176,8 @@ def spmv_pallas(
     """
     T, Bd, Bs = tiles.shape
     nSB, _, K = x_blocks.shape
+    if not interpret:
+        _check_grid_fits(T)
     # 'bool' occupancy tiles accumulate 0/1 mass on the plus_times kernel.
     kernel = _kernel_min_plus if semiring == "min_plus" else _kernel_plus_times
 
@@ -189,7 +209,7 @@ def spmv_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_dst_blocks, Bd, K), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
@@ -283,6 +303,8 @@ def spmv_pallas_compact(
         else _kernel_plus_times_compact
     )
     G = int(perm.shape[0])
+    if not interpret:
+        _check_grid_fits(G)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
@@ -314,7 +336,7 @@ def spmv_pallas_compact(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_dst_blocks, Bd, K), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -433,7 +433,7 @@ def blocked_spmv(
     active: Optional[jnp.ndarray] = None,
     *,
     active_on: str = "src",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     compact: bool = False,
     grid_bucket: Optional[int] = None,
     assume_fits: bool = False,
@@ -442,6 +442,8 @@ def blocked_spmv(
 
     Args:
       x: [n] or [n, K] vertex state (K = multi-source lanes).
+      interpret: Pallas interpret mode; ``None`` resolves through
+        :func:`default_interpret` (compiled on a TPU, interpreted elsewhere).
       active: optional bool[n] frontier; tiles disjoint from it are skipped
         (fetch + compute).  With ``active_on='src'`` the frontier lives on
         source vertices (columns; push multicast), with ``'dst'`` on
@@ -479,6 +481,8 @@ def blocked_spmv(
       kernel-path analogue of ``core.sem.IOStats``.  Identical across the
       full and compacted grids.
     """
+    if interpret is None:
+        interpret = default_interpret()
     if not interpret and bg.tile_order != "dest":
         # The accumulate-on-flush read of a revisited output block is exact
         # in interpret mode (every step operates on the real buffer) but is

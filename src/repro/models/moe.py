@@ -34,22 +34,10 @@ __all__ = ["init_moe", "moe_ffn", "moe_ffn_ep", "moe_capacity"]
 
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
-    """shard_map across JAX spellings: ``jax.shard_map(check_vma=...)`` on
-    new JAX, ``jax.experimental.shard_map.shard_map(check_rep=...)`` on old.
-    Replication checking is off either way (the EP body mixes pmean'd and
-    sharded outputs)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-    from jax.experimental.shard_map import shard_map as legacy_sm
-
-    return legacy_sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    """``jax.shard_map`` with replication checking off (the EP body mixes
+    pmean'd and sharded outputs)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def init_moe(mk: Mk, cfg: ModelConfig):

@@ -276,3 +276,12 @@ class TestDurableQueueProtocol:
                       clock=ManualClock())
         with pytest.raises(TypeError):
             supervise_workers(q, _vec_work)
+
+    def test_supervise_workers_refuses_when_parent_holds_tpu(
+            self, tmp_path, monkeypatch):
+        import jax
+
+        q = self.make(tmp_path / "q")
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(RuntimeError, match="holds the TPU"):
+            run_workers(q, _vec_work, processes=2)
