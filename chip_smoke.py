@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import sys
 import time
 import traceback
@@ -247,7 +248,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    from benchmarks.compile_cache import use_compile_cache
+    from bench.compile_cache import use_compile_cache
 
     import jax
 
@@ -255,7 +256,9 @@ def main(argv=None) -> int:
         print(f"chip_smoke: needs a TPU, JAX found {jax.default_backend()!r}",
               file=sys.stderr)
         return 2
-    print(f"# compile cache: {use_compile_cache()}", flush=True)
+    # JAX reads JAX_COMPILATION_CACHE_DIR itself where it is set.
+    cache = os.environ.get("JAX_COMPILATION_CACHE_DIR") or use_compile_cache()
+    print(f"# compile cache: {cache}", flush=True)
     if args.scale < TARGET_SCALE:
         print(f"# scale cut: RMAT scale {TARGET_SCALE} -> {args.scale}",
               flush=True)

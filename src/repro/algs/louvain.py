@@ -27,7 +27,6 @@ uses jnp segment reductions (community volumes and modularity terms).
 from __future__ import annotations
 
 import dataclasses
-import time
 
 import jax.numpy as jnp
 import numpy as np
@@ -44,7 +43,6 @@ class LouvainResult:
     levels: int
     bytes_written: int  # edge bytes rewritten (materialize path only)
     gather_ops: int  # per-edge indirection gathers (Graphyti path overhead)
-    level_times: list
 
 
 def modularity(src, dst, w, comm, two_m: float) -> float:
@@ -119,7 +117,6 @@ def louvain(
     comm_orig = np.arange(n, dtype=np.int64)
     bytes_written = 0
     gather_ops = 0
-    level_times = []
 
     # Level-local edge view (materialize path replaces these per level).
     src, dst, w = src0.astype(np.int64), dst0.astype(np.int64), w0
@@ -128,7 +125,6 @@ def louvain(
 
     levels = 0
     for _ in range(max_levels):
-        t0 = time.perf_counter()
         if not materialize and levels > 0:
             # Graphyti path: aggregate THROUGH the indirection each level —
             # two gathers per original edge (comm of each endpoint).
@@ -141,7 +137,6 @@ def louvain(
         comm, improved = _local_moves(src, dst, w, comm, two_m, max_sweeps)
         levels += 1
         if not improved:
-            level_times.append(time.perf_counter() - t0)
             break
         # Relabel communities densely.
         uniq, comm_dense = np.unique(comm, return_inverse=True)
@@ -154,7 +149,6 @@ def louvain(
         else:
             # Update only the O(n) indirection vector; edges untouched.
             comm_orig = comm_dense[comm_orig]
-        level_times.append(time.perf_counter() - t0)
         if len(uniq) == nn:  # nothing merged
             break
 
@@ -165,7 +159,6 @@ def louvain(
         levels=levels,
         bytes_written=int(bytes_written),
         gather_ops=int(gather_ops),
-        level_times=level_times,
     )
 
 

@@ -106,7 +106,7 @@ full overlap) and ``sum_q cost(frontier_q)`` (disjoint frontiers) — while
 Q sequential sweeps always pay the sum.  Per-query amortized I/O
 (``host_bytes / Q`` under residency='host') therefore drops toward 1/Q as
 frontiers overlap, which is the serving-path headline
-(`benchmarks/bench_multisource.py` sweeps it).  Every dispatch decision
+(``tests/test_multisource.py`` pins the drop).  Every dispatch decision
 (Beamer direction, density three-way, pow2 cap buckets) keys on the union
 masses, so a batched superstep executes exactly like a single-query sweep
 of the union frontier; ``messages`` alone stays per-lane-exact (the sum
@@ -144,7 +144,7 @@ just skipping them.  The order changes ONLY the schedule: values, tile
 fetches, records, and bytes are order-invariant (the per-run flush
 accumulates, so a destination block split across several curve runs sums
 to the same result); the one counter that moves is ``IOStats.x_fetches``,
-which ``benchmarks/bench_tile_order.py`` sweeps.  The blocked view must be
+which ``tests/test_tile_order.py`` counts.  The blocked view must be
 built with the matching order (``device_graph(..., tile_order=...)``);
 ``repro.Graph`` sessions key their tile cache by ``(encoding,
 tile_order)`` and handle this automatically.
@@ -693,8 +693,10 @@ def _multicast(sg, x, active, sr, *, direction, reverse, y_init, pol):
     if pol.chunk_cap is None and not (
         pol.adaptive_cap and backend in ("scan", "compact")
     ):
-        return spmv(sg, x, active, sr, direction=direction, reverse=reverse,
-                    y_init=y_init, backend=backend, interpret=pol.interpret)
+        with jax.named_scope("graphyti.dense"):
+            return spmv(sg, x, active, sr, direction=direction,
+                        reverse=reverse, y_init=y_init, backend=backend,
+                        interpret=pol.interpret)
     if backend in ("blocked", "blocked_compact"):
         always_compact = backend == "blocked_compact"
         from ..kernels.spmv import tile_activity
@@ -707,17 +709,20 @@ def _multicast(sg, x, active, sr, *, direction, reverse, y_init, pol):
         )
 
         def compact_arm(_):
-            return blocked_backend_spmv(
-                sg, x, active, sr, direction=direction, reverse=reverse,
-                y_init=y_init, compact=True, interpret=pol.interpret,
-                grid_bucket=cap, assume_fits=True,
-            )
+            with jax.named_scope("graphyti.compact"):
+                return blocked_backend_spmv(
+                    sg, x, active, sr, direction=direction, reverse=reverse,
+                    y_init=y_init, compact=True, interpret=pol.interpret,
+                    grid_bucket=cap, assume_fits=True,
+                )
 
         def dense_arm(_):
-            return blocked_backend_spmv(
-                sg, x, active, sr, direction=direction, reverse=reverse,
-                y_init=y_init, compact=always_compact, interpret=pol.interpret,
-            )
+            with jax.named_scope("graphyti.dense"):
+                return blocked_backend_spmv(
+                    sg, x, active, sr, direction=direction, reverse=reverse,
+                    y_init=y_init, compact=always_compact,
+                    interpret=pol.interpret,
+                )
 
         return jax.lax.cond(use_compact, compact_arm, dense_arm, None)
 
@@ -736,14 +741,18 @@ def _multicast(sg, x, active, sr, *, direction, reverse, y_init, pol):
     def compact_arm(_):
         # use_compact already proved the live chunks fit the cap, so skip
         # compact_spmv's own overflow cond (it would trace a dead full scan).
-        if pol.adaptive_cap:
-            return _adaptive_compact(store, x, active, sr, y_init, reverse,
-                                     cap, n_act_chunks)
-        return compact_spmv(store, x, active, sr, y_init=y_init,
-                            reverse=reverse, chunk_cap=cap, assume_fits=True)
+        with jax.named_scope("graphyti.compact"):
+            if pol.adaptive_cap:
+                return _adaptive_compact(store, x, active, sr, y_init,
+                                         reverse, cap, n_act_chunks)
+            return compact_spmv(store, x, active, sr, y_init=y_init,
+                                reverse=reverse, chunk_cap=cap,
+                                assume_fits=True)
 
     def dense_arm(_):
-        return sem_spmv(store, x, active, sr, y_init=y_init, reverse=reverse)
+        with jax.named_scope("graphyti.dense"):
+            return sem_spmv(store, x, active, sr, y_init=y_init,
+                            reverse=reverse)
 
     return jax.lax.cond(use_compact, compact_arm, dense_arm, None)
 
@@ -804,14 +813,15 @@ def _dispatch(sg, x, active, sr, *, direction, reverse, y_init, pol):
     def sparse(_):
         # use_p2p proved the frontier fits the static caps, so the
         # adaptive ladder tops out exactly there and every bucket is safe.
-        if pol.adaptive_cap:
-            return _adaptive_p2p(sg, x, active, sr, direction=direction,
-                                 y_init=y_init, vcap=vcap, ecap=ecap,
-                                 n_act=n_act, act_edges=act_edges)
-        return p2p_spmv(
-            sg, x, active, sr, direction=direction, vcap=vcap, ecap=ecap,
-            y_init=y_init,
-        )
+        with jax.named_scope("graphyti.p2p"):
+            if pol.adaptive_cap:
+                return _adaptive_p2p(sg, x, active, sr, direction=direction,
+                                     y_init=y_init, vcap=vcap, ecap=ecap,
+                                     n_act=n_act, act_edges=act_edges)
+            return p2p_spmv(
+                sg, x, active, sr, direction=direction, vcap=vcap,
+                ecap=ecap, y_init=y_init,
+            )
 
     def not_sparse(_):
         return _multicast(sg, x, active, sr, direction=direction,
@@ -969,8 +979,9 @@ def traverse(
         mode = "out"  # 'auto' without pull views: push is the only option
 
     def _push(_):
-        return _dispatch(sg, x, active, sr, direction="out", reverse=False,
-                         y_init=y_init, pol=pol)
+        with jax.named_scope("graphyti.push"):
+            return _dispatch(sg, x, active, sr, direction="out",
+                             reverse=False, y_init=y_init, pol=pol)
 
     if mode == "out":
         y, st = _push(None)
@@ -983,8 +994,9 @@ def traverse(
     xm = jnp.where(mask, x, jnp.asarray(sr.identity, x.dtype))
 
     def _pull(_):
-        return _dispatch(sg, xm, unexplored, sr, direction="in",
-                         reverse=False, y_init=y_init, pol=pol)
+        with jax.named_scope("graphyti.pull"):
+            return _dispatch(sg, xm, unexplored, sr, direction="in",
+                             reverse=False, y_init=y_init, pol=pol)
 
     if mode == "in":
         y, st = _pull(None)

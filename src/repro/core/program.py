@@ -60,6 +60,7 @@ import numpy as np
 from .engine import ExecutionPolicy, ResidencyError, traverse
 from .sem import IOStats, SemGraph
 from .semiring import PLUS_TIMES, Semiring
+from .spans import host_read
 
 __all__ = [
     "Frontier",
@@ -307,17 +308,11 @@ def run_program(
     budget = max_supersteps if max_supersteps is not None \
         else prog.max_supersteps(sg)
 
+    from .recovery import superstep
+
     def body(carry):
         state, io, it, _ = carry
-        fr = prog.frontier(sg, state)
-        gathered, st = prog.gather(sg, state, fr, pol)
-        state, activated = prog.apply(sg, state, gathered)
-        state, st_act = prog.activate(sg, state, pol)
-        io = io + st
-        if st_act is not None:  # static: the program either has the hook or not
-            io = io + st_act
-        io = io._replace(supersteps=io.supersteps + 1)
-        done = prog.converged(sg, state, activated)
+        state, io, done = superstep(sg, prog, pol, state, io)
         return state, io, it + 1, done
 
     def cond(carry):
@@ -349,22 +344,12 @@ def _batched_step_fn(sg, prog: VertexProgram, pol: ExecutionPolicy):
     parity; see ``_loopify``).  Cached across runs like
     ``recovery._SEG_CACHE`` — the cached closure holds ``sg`` strongly, so
     the ``id(sg)`` key cannot be recycled while cached."""
+    from .recovery import superstep
     from .residency import _loopify
 
     def build():
-        def body(state, io):
-            fr = prog.frontier(sg, state)
-            gathered, st = prog.gather(sg, state, fr, pol)
-            state2, activated = prog.apply(sg, state, gathered)
-            state2, st_act = prog.activate(sg, state2, pol)
-            io = io + st
-            if st_act is not None:
-                io = io + st_act
-            io = io._replace(supersteps=io.supersteps + 1)
-            conv = prog.converged_cols(sg, state2, activated)
-            return state2, io, conv
-
-        return _loopify(body)
+        return _loopify(lambda state, io: superstep(
+            sg, prog, pol, state, io, converged=prog.converged_cols))
 
     try:
         key = (id(sg), type(prog), tuple(sorted(prog.__dict__.items())), pol)
@@ -526,7 +511,7 @@ def run_program_batched(
     done_at = np.full(Q, -1, np.int64)
     io = IOStats.zero()
     it = 0
-    done = (bool(prog.converged(sg, state, None))
+    done = (bool(host_read(prog.converged(sg, state, None)))
             if prog.check_initial_convergence else False)
     if done:
         done_at[:] = 0
@@ -551,9 +536,10 @@ def run_program_batched(
     try:
         while not done and it < budget:
             maybe_fail(_plan, it)
-            state, io, conv = step(state, io)
+            with jax.profiler.TraceAnnotation("graphyti.superstep", it=it):
+                state, io, conv = step(state, io)
+                conv_np = host_read(conv)
             it += 1
-            conv_np = np.asarray(conv)
             for i, q in enumerate(cur):
                 if conv_np[i] and done_at[q] < 0:
                     done_at[q] = it

@@ -41,7 +41,7 @@ recovery here covers all six paper algorithms plus every user
     ``DeviceFailure``) to the BSP loop: the driver raises at injected
     supersteps, the supervisor replays from the newest checkpoint, and the
     final result is gated bitwise against the uninterrupted run in
-    ``tests/test_recovery.py`` and ``benchmarks/run.py --smoke``.
+    ``tests/test_recovery.py``.
 """
 from __future__ import annotations
 
@@ -63,6 +63,7 @@ from ..checkpoint import CheckpointManager, latest_step, load_extra
 from ..distributed.fault import DeviceFailure, FailurePlan
 from .engine import ExecutionPolicy
 from .sem import IOStats
+from .spans import host_read
 
 __all__ = [
     "CheckpointMismatchError",
@@ -333,14 +334,38 @@ def _segment_fn(sg, prog, pol):
         return _build_segment_fn(sg, prog, pol)
 
 
-def superstep_body(sg, prog, pol):
-    """THE BSP superstep as a carry -> carry function.
+def superstep(sg, prog, pol, state, io: IOStats, converged=None):
+    """THE BSP superstep, traced: frontier, gather, apply, activate,
+    IOStats accumulation, convergence test.  Returns ``(state, io, done)``
+    with ``done = converged(sg, state, activated)`` (default
+    ``prog.converged``; the batched driver passes ``prog.converged_cols``).
 
-    One place defines what a superstep is — frontier, gather, apply,
-    activate, IOStats accumulation, convergence test — and both consumers
-    trace exactly this function: :func:`_build_segment_fn` wraps it in the
-    segment ``lax.while_loop`` the device driver executes, and
-    :func:`repro.analysis.analyze` traces it into the jaxpr the static
+    Each phase runs under its device scope (``graphyti.frontier``,
+    ``.gather``, ``.apply``, ``.activate``, ``.converged``), so every device
+    driver that traces a superstep names its ops alike in a profile."""
+    with jax.named_scope("graphyti.frontier"):
+        fr = prog.frontier(sg, state)
+    with jax.named_scope("graphyti.gather"):
+        gathered, st = prog.gather(sg, state, fr, pol)
+    with jax.named_scope("graphyti.apply"):
+        state, activated = prog.apply(sg, state, gathered)
+    with jax.named_scope("graphyti.activate"):
+        state, st_act = prog.activate(sg, state, pol)
+    io = io + st
+    if st_act is not None:  # static: the program either has the hook or not
+        io = io + st_act
+    io = io._replace(supersteps=io.supersteps + 1)
+    with jax.named_scope("graphyti.converged"):
+        done = (converged or prog.converged)(sg, state, activated)
+    return state, io, done
+
+
+def superstep_body(sg, prog, pol):
+    """:func:`superstep` as a carry -> carry function.
+
+    Both consumers trace exactly this function: :func:`_build_segment_fn`
+    wraps it in the segment ``lax.while_loop`` the device driver executes,
+    and :func:`repro.analysis.analyze` traces it into the jaxpr the static
     rules walk.  That sharing is the analyzer's soundness argument: the
     jaxpr it inspects IS the loop body that runs, not a re-derivation.
 
@@ -351,15 +376,7 @@ def superstep_body(sg, prog, pol):
 
     def body(carry):
         state, io, it, _, stop = carry
-        fr = prog.frontier(sg, state)
-        gathered, st = prog.gather(sg, state, fr, pol)
-        state, activated = prog.apply(sg, state, gathered)
-        state, st_act = prog.activate(sg, state, pol)
-        io = io + st
-        if st_act is not None:
-            io = io + st_act
-        io = io._replace(supersteps=io.supersteps + 1)
-        done = prog.converged(sg, state, activated)
+        state, io, done = superstep(sg, prog, pol, state, io)
         return state, io, it + 1, done, stop
 
     return body
@@ -440,7 +457,7 @@ def run_program_checkpointed(
            if checkpoint is not None else None)
     io = IOStats.zero()
     it = 0
-    done = (bool(prog.converged(sg, state, None))
+    done = (bool(host_read(prog.converged(sg, state, None)))
             if prog.check_initial_convergence else False)
     if resume and ctx is not None:
         hit = ctx.try_restore(sg, state)
@@ -462,11 +479,12 @@ def run_program_checkpointed(
             nf = _next_planned(_plan, it + 1)
             if nf is not None:
                 stop = min(stop, nf)
-            state, io, it_a, done_a, _ = seg(
-                state, io, jnp.asarray(it, jnp.int32),
-                jnp.zeros((), bool), jnp.asarray(stop, jnp.int32),
-            )
-            it, done = int(it_a), bool(done_a)
+            with jax.profiler.TraceAnnotation("graphyti.segment", stop=stop):
+                state, io, it_a, done_a, _ = seg(
+                    state, io, jnp.asarray(it, jnp.int32),
+                    jnp.zeros((), bool), jnp.asarray(stop, jnp.int32),
+                )
+            it, done = int(host_read(it_a)), bool(host_read(done_a))
             finished = done or it >= budget
             if ctx is not None and ctx.due(it, finished):
                 act = prog.frontier(sg, state).active

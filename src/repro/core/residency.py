@@ -93,6 +93,7 @@ from .sem import (
     pad_state,
 )
 from .semiring import Semiring
+from .spans import host_read
 
 __all__ = [
     "HostBlockedStore",
@@ -141,27 +142,44 @@ def inject_stream_faults(hook):
         _FAULT_HOOK = prev
 
 
-def _staged(pol: ExecutionPolicy, fn):
-    """Run ``fn`` (one batch's host->device staging) under the policy's
-    bounded retry-with-backoff.  Returns ``(result, n_retries)``; raises
-    :class:`StreamFailure` when ``stream_retries + 1`` attempts all fail.
-    Retries are safe by construction: staging is a pure read of pinned
-    host arrays — no state mutates until the shipped payload is used."""
-    attempts = int(pol.stream_retries) + 1
-    last = None
-    for a in range(attempts):
-        try:
-            if _FAULT_HOOK is not None:
-                _FAULT_HOOK()
-            return fn(), a
-        except Exception as e:  # noqa: BLE001 — any staging error is retryable
-            last = e
-            if a + 1 < attempts and pol.stream_backoff_s > 0:
-                time.sleep(pol.stream_backoff_s * (2 ** a))
+def _staged(pol: ExecutionPolicy, build, units: int):
+    """Stage one batch: ``build()`` gathers its host arrays, which then
+    ``device_put`` under the policy's bounded retry-with-backoff.  Returns
+    ``(device arrays, nbytes, n_retries)``; raises :class:`StreamFailure`
+    when ``stream_retries + 1`` attempts all fail.  Retries are safe by
+    construction: staging is a pure read of pinned host arrays — no state
+    mutates until the shipped payload is used.
+
+    The whole of it is the ``graphyti.stage`` span; its ``bytes`` argument
+    is ``nbytes``, the exact count ``IOStats.host_bytes`` adds (padding
+    included) before its int32 wrap, and ``units`` the batch's live
+    chunks, tiles or edge lanes."""
+    with jax.profiler.TraceAnnotation("graphyti.stage", units=units) as span:
+        arrays = build()
+        nbytes = sum(a.nbytes for a in arrays)
+        span.set_metadata(bytes=nbytes)
+        attempts = int(pol.stream_retries) + 1
+        last = None
+        for a in range(attempts):
+            try:
+                if _FAULT_HOOK is not None:
+                    _FAULT_HOOK()
+                return tuple(jax.device_put(x) for x in arrays), nbytes, a
+            except Exception as e:  # noqa: BLE001 — any staging error is retryable
+                last = e
+                if a + 1 < attempts and pol.stream_backoff_s > 0:
+                    time.sleep(pol.stream_backoff_s * (2 ** a))
     raise StreamFailure(
         f"host->device stream failed after {attempts} attempts "
         f"(stream_retries={pol.stream_retries}): {last!r}"
     ) from last
+
+
+def _enqueue(kern, *args):
+    """Launch one batch kernel (JAX dispatches it asynchronously) under the
+    ``graphyti.enqueue`` span."""
+    with jax.profiler.TraceAnnotation("graphyti.enqueue"):
+        return kern(*args)
 
 
 def _pow2_at_least(k: int) -> int:
@@ -404,7 +422,9 @@ def _chunk_batch_fn(sr: Semiring, n: int, gather_on_major: bool,
     """Jitted scan over one staging batch of chunks — the same per-chunk
     fetch (:func:`~repro.core.sem._make_fetch`) the device paths run, so
     each live chunk's scatter is bitwise the device scatter.  ``valid``
-    masks padding slots (whole-chunk no-ops)."""
+    masks padding slots (whole-chunk no-ops).  Its scan runs under the
+    ``graphyti.chunk_scan`` scope, the name of the device residency's
+    chunk scans too."""
 
     def run(y, msgs, xp, active, major, minor, w, valid):
         fetch = _make_fetch(sr, xp, active, n, gather_on_major, has_w)
@@ -415,7 +435,9 @@ def _chunk_batch_fn(sr: Semiring, n: int, gather_on_major: bool,
             y, mm = fetch(y, mj, mi, wc if has_w else None, v)
             return (y, msgs + mm), None
 
-        (y, msgs), _ = jax.lax.scan(body, (y, msgs), (major, minor, w, valid))
+        with jax.named_scope("graphyti.chunk_scan"):
+            (y, msgs), _ = jax.lax.scan(body, (y, msgs),
+                                        (major, minor, w, valid))
         return y, msgs
 
     return jax.jit(run)
@@ -474,15 +496,17 @@ def _stream_chunks(hg: HostGraph, store: HostChunkStore, x, active,
     xp = pad_state(x, sr)
     y = _pad_y_init(sr, xp, y_init, n)
     msgs = jnp.zeros((), jnp.int32)
-
-    # numpy mirror of chunk_activity: frontier prefix sums over [lo, hi].
-    act_np = np.asarray(active)
-    cs = np.cumsum(act_np.astype(np.int64))
-    prefix = np.concatenate([np.zeros(1, np.int64), cs, cs[-1:]])
-    per_chunk = prefix[store.hi + 1] - prefix[store.lo]
-    live = np.flatnonzero(per_chunk > 0)
-
     B = int(pol.stream_buffer)
+
+    with jax.profiler.TraceAnnotation("graphyti.plan") as span:
+        # numpy mirror of chunk_activity: frontier prefix sums over [lo, hi].
+        cs = np.cumsum(host_read(active).astype(np.int64))
+        prefix = np.concatenate([np.zeros(1, np.int64), cs, cs[-1:]])
+        per_chunk = prefix[store.hi + 1] - prefix[store.lo]
+        live = np.flatnonzero(per_chunk > 0)
+        batches = [live[i:i + B] for i in range(0, len(live), B)]
+        span.set_metadata(live=int(live.size), units=C)
+
     kern = _chunk_batch_fn(sr, n, gather_on_major, has_w)
     # Unweighted stores ship no weight column; the kernel's w operand is a
     # device-side dummy created once (zero host-link traffic).
@@ -494,36 +518,32 @@ def _stream_chunks(hg: HostGraph, store: HostChunkStore, x, active,
     def ship(ids):
         nonlocal retr
         k = len(ids)
-        if k < B:  # last batch: pad with chunk 0, masked whole-chunk
-            idx = np.zeros(B, np.int64)
-            idx[:k] = ids
-        else:
-            idx = ids
-        major = np.ascontiguousarray(store.major[idx])
-        minor = np.ascontiguousarray(store.minor[idx])
-        valid = np.zeros(B, bool)
-        valid[:k] = True
-        nb = major.nbytes + minor.nbytes + valid.nbytes
-        if has_w:
-            w = np.ascontiguousarray(store.w[idx])
-            nb += w.nbytes
 
-        def put():
-            wd = jax.device_put(w) if has_w else w_dummy
-            return (jax.device_put(major), jax.device_put(minor), wd,
-                    jax.device_put(valid))
+        def build():
+            if k < B:  # last batch: pad with chunk 0, masked whole-chunk
+                idx = np.zeros(B, np.int64)
+                idx[:k] = ids
+            else:
+                idx = ids
+            valid = np.zeros(B, bool)
+            valid[:k] = True
+            arrays = (np.ascontiguousarray(store.major[idx]),
+                      np.ascontiguousarray(store.minor[idx]), valid)
+            if has_w:
+                arrays += (np.ascontiguousarray(store.w[idx]),)
+            return arrays
 
-        payload, r = _staged(pol, put)
+        arrays, nb, r = _staged(pol, build, k)
         retr += r
-        return payload, nb
+        major, minor, valid = arrays[:3]
+        return (major, minor, arrays[3] if has_w else w_dummy, valid), nb
 
-    batches = [live[i:i + B] for i in range(0, len(live), B)]
     if batches:
         cur, cur_nb = ship(batches[0])
         for i in range(len(batches)):
             host_bytes += cur_nb
             # async dispatch: the copy below overlaps this batch's compute.
-            y_msgs = kern(y, msgs, xp, active, *cur)
+            y_msgs = _enqueue(kern, y, msgs, xp, active, *cur)
             if i + 1 < len(batches):
                 nxt, nxt_nb = ship(batches[i + 1])
                 peak = max(peak, cur_nb + nxt_nb)
@@ -578,6 +598,38 @@ def _host_select_blocked(hg: HostGraph, direction: str, reverse: bool):
     raise NotImplementedError("blocked backend: direction='in' with reverse")
 
 
+def _tile_batches(live, run_id, dbid, B: int) -> list:
+    """Staging batches of the live tile positions ``live``, as
+    ``(positions, dst blocks flushed by the batch)``, under the two rules
+    of :func:`_stream_tiles`.  Live runs group consecutive live steps by
+    ORIGINAL run id (the same keying compact_tile_order uses, so runs that
+    become adjacent when tiles between them go inactive are NOT merged)."""
+    if not live.size:
+        return []
+    lr = run_id[live]
+    starts = np.flatnonzero(np.concatenate([[True], lr[1:] != lr[:-1]]))
+    ends = np.append(starts[1:], live.size)
+    runs = [live[s:e] for s, e in zip(starts, ends)]
+    batches = []
+    cur, cur_blocks, cur_count = [], set(), 0
+    earlier: set = set()
+    for r in runs:
+        b = int(dbid[r[0]])
+        split = cur and (
+            cur_count + len(r) > B            # buffer budget
+            or (b in earlier and b in cur_blocks)  # rule 2
+        )
+        if split:
+            batches.append((np.concatenate(cur), frozenset(cur_blocks)))
+            earlier |= cur_blocks
+            cur, cur_blocks, cur_count = [], set(), 0
+        cur.append(r)
+        cur_blocks.add(b)
+        cur_count += len(r)
+    batches.append((np.concatenate(cur), frozenset(cur_blocks)))
+    return batches
+
+
 def _stream_tiles(hg: HostGraph, x, active, sr: Semiring, *, direction: str,
                   reverse: bool, y_init, pol: ExecutionPolicy):
     """The blocked backends' host execution.
@@ -622,17 +674,20 @@ def _stream_tiles(hg: HostGraph, x, active, sr: Semiring, *, direction: str,
     xp = jnp.full((nSB * bs, k), ident, xv.dtype).at[:n].set(xv)
     x_blocks = xp.reshape(nSB, bs, k).astype(jnp.float32)
 
-    # numpy mirror of tile_activity.
-    act_np = np.asarray(active)
     if active_on == "src":
         blk, nb_blocks, bid = bs, nSB, store.sbid
     else:
         blk, nb_blocks, bid = bd, nDB, store.dbid
-    ap = np.zeros(nb_blocks * blk, bool)
-    ap[:n] = act_np
-    act_blk = ap.reshape(nb_blocks, blk).any(axis=1)
-    act_tile = act_blk[bid]
-    live = np.flatnonzero(act_tile)
+    with jax.profiler.TraceAnnotation("graphyti.plan") as span:
+        run_id = np.cumsum(store.first) - 1
+        # numpy mirror of tile_activity.
+        ap = np.zeros(nb_blocks * blk, bool)
+        ap[:n] = host_read(active)
+        act_blk = ap.reshape(nb_blocks, blk).any(axis=1)
+        live = np.flatnonzero(act_blk[bid])
+        batches = _tile_batches(live, run_id, store.dbid,
+                                int(pol.stream_buffer))
+        span.set_metadata(live=int(live.size), units=store.num_tiles)
 
     ident_out = np.inf if store.semiring == "min_plus" else 0.0
     carry = jnp.full((nDB, bd, k), ident_out, jnp.float32)
@@ -642,38 +697,10 @@ def _stream_tiles(hg: HostGraph, x, active, sr: Semiring, *, direction: str,
     peak = 0
     retr = 0
 
-    if live.size:
-        # live runs: group consecutive live steps by ORIGINAL run id (the
-        # same keying compact_tile_order uses, so runs that become adjacent
-        # when tiles between them go inactive are NOT merged).
-        run_id = np.cumsum(store.first) - 1
-        lr = run_id[live]
-        starts = np.flatnonzero(np.concatenate([[True], lr[1:] != lr[:-1]]))
-        ends = np.append(starts[1:], live.size)
-        runs = [live[s:e] for s, e in zip(starts, ends)]
-        run_block = [int(store.dbid[r[0]]) for r in runs]
-
-        B = int(pol.stream_buffer)
-        batches = []  # (live positions, dst blocks flushed by this batch)
-        cur, cur_blocks, cur_count = [], set(), 0
-        earlier: set = set()
-        for r, b in zip(runs, run_block):
-            split = cur and (
-                cur_count + len(r) > B            # buffer budget
-                or (b in earlier and b in cur_blocks)  # rule 2
-            )
-            if split:
-                batches.append((np.concatenate(cur), frozenset(cur_blocks)))
-                earlier |= cur_blocks
-                cur, cur_blocks, cur_count = [], set(), 0
-            cur.append(r)
-            cur_blocks.add(b)
-            cur_count += len(r)
-        batches.append((np.concatenate(cur), frozenset(cur_blocks)))
-
+    if batches:
         kern = _tile_batch_fn(store.semiring, nDB, interpret)
 
-        def ship(pos):
+        def build(pos):
             kk = len(pos)
             G = _pow2_at_least(kk)
             tiles = np.zeros((G, bd, bs), np.float32)
@@ -708,12 +735,12 @@ def _stream_tiles(hg: HostGraph, x, active, sr: Semiring, *, direction: str,
                 seen.add(blk_id)
             accum_b[:kk] = acc_run[np.cumsum(first_b[:kk]) - 1]
             nact = np.array([kk], np.int32)
-            arrs = (tiles, perm, dbid_b, sbid_b, first_b, last_b, accum_b,
+            return (tiles, perm, dbid_b, sbid_b, first_b, last_b, accum_b,
                     nact)
-            nb = sum(a.nbytes for a in arrs)
+
+        def ship(pos):
             nonlocal retr
-            payload, r = _staged(
-                pol, lambda: tuple(jax.device_put(a) for a in arrs))
+            payload, nb, r = _staged(pol, lambda: build(pos), len(pos))
             retr += r
             return payload, nb
 
@@ -721,7 +748,7 @@ def _stream_tiles(hg: HostGraph, x, active, sr: Semiring, *, direction: str,
         cur_pay, cur_nb = ship(batches[0][0])
         for i, (_, blocks) in enumerate(batches):
             host_bytes += cur_nb
-            y_b = kern(*cur_pay, x_blocks)  # async dispatch
+            y_b = _enqueue(kern, *cur_pay, x_blocks)  # async dispatch
             if i + 1 < len(batches):
                 nxt_pay, nxt_nb = ship(batches[i + 1][0])  # overlaps compute
                 peak = max(peak, cur_nb + nxt_nb)
@@ -794,44 +821,41 @@ def _host_p2p(hg: HostGraph, x, active, sr: Semiring, *, direction: str,
     xp = pad_state(x, sr)
     y0 = _pad_y_init(sr, xp, y_init, n)
 
-    act_np = np.asarray(active)
-    act_idx = np.flatnonzero(act_np)
-    deg = (indptr[act_idx + 1] - indptr[act_idx]).astype(np.int64)
-    total = int(deg.sum())
+    with jax.profiler.TraceAnnotation("graphyti.plan") as span:
+        act_idx = np.flatnonzero(host_read(active))
+        deg = (indptr[act_idx + 1] - indptr[act_idx]).astype(np.int64)
+        total = int(deg.sum())
+        span.set_metadata(live=len(act_idx), units=n)
     E = int(ecap)
     has_w = w is not None
-    major = np.full(E, n, np.int32)
-    minor = np.full(E, n, np.int32)
-    ew = np.zeros(E, np.float32) if has_w else None
-    valid = np.zeros(E, bool)
-    t = min(total, E)  # the gate guarantees total <= ecap; mirror the
-    if t:              # device's lane truncation if it ever doesn't
-        offs = np.cumsum(deg)
-        row_start = offs - deg
-        p = np.arange(t, dtype=np.int64)
-        kix = np.searchsorted(offs, p, side="right")
-        e = indptr[act_idx[kix]].astype(np.int64) + (p - row_start[kix])
-        major[:t] = np.repeat(act_idx.astype(np.int32), deg)[:t]
-        minor[:t] = np.asarray(indices)[e].astype(np.int32)
-        if has_w:
-            ew[:t] = np.asarray(w, np.float32)[e]
-        valid[:t] = True
+    # The gate guarantees total <= ecap; mirror the device's lane
+    # truncation if it ever doesn't.
+    t = min(total, E)
 
-    payload = [major, minor, valid] + ([ew] if has_w else [])
-    nb = sum(a.nbytes for a in payload)
+    def build():
+        major = np.full(E, n, np.int32)
+        minor = np.full(E, n, np.int32)
+        ew = np.zeros(E, np.float32) if has_w else None
+        valid = np.zeros(E, bool)
+        if t:
+            offs = np.cumsum(deg)
+            row_start = offs - deg
+            p = np.arange(t, dtype=np.int64)
+            kix = np.searchsorted(offs, p, side="right")
+            e = indptr[act_idx[kix]].astype(np.int64) + (p - row_start[kix])
+            major[:t] = np.repeat(act_idx.astype(np.int32), deg)[:t]
+            minor[:t] = np.asarray(indices)[e].astype(np.int32)
+            if has_w:
+                ew[:t] = np.asarray(w, np.float32)[e]
+            valid[:t] = True
+        return (major, minor, valid) + ((ew,) if has_w else ())
+
+    arrays, nb, retr = _staged(pol, build, t)
     hg._note_stage(nb)
-
-    def put():
-        dm = jax.device_put(major)
-        dn = jax.device_put(minor)
-        dv = jax.device_put(valid)
-        # dw: unused operand when not has_w
-        dw = jax.device_put(ew) if has_w else dv
-        return dm, dn, dv, dw
-
-    (dm, dn, dv, dw), retr = _staged(pol, put)
+    dm, dn, dv = arrays[:3]
+    dw = arrays[3] if has_w else dv  # dw: unused operand when not has_w
     run = _p2p_tail_fn(sr, n, has_w, direction == "out")
-    y = run(y0, xp, dm, dn, dw, dv)
+    y = _enqueue(run, y0, xp, dm, dn, dw, dv)
 
     rec = _store_record_bytes(w)
     st = IOStats(
@@ -881,13 +905,14 @@ def _host_dispatch(hg, x, active, sr, *, direction, reverse, y_init, pol):
                                reverse=reverse, y_init=y_init, pol=pol)
     vcap = pol.vcap if pol.vcap is not None else hg.n
     ecap = pol.ecap if pol.ecap is not None else max(int(hg.m), 1)
-    act_edges = frontier_edge_mass(deg, active)
-    n_act = jnp.sum(active.astype(jnp.int32))
-    use_p2p = bool(
-        (act_edges <= jnp.int32(pol.switch_fraction * hg.m))
-        & (act_edges <= ecap)
-        & (n_act <= vcap)
-    )
+    with jax.profiler.TraceAnnotation("graphyti.plan"):
+        act_edges = frontier_edge_mass(deg, active)
+        n_act = jnp.sum(active.astype(jnp.int32))
+        use_p2p = bool(host_read(
+            (act_edges <= jnp.int32(pol.switch_fraction * hg.m))
+            & (act_edges <= ecap)
+            & (n_act <= vcap)
+        ))
     if use_p2p:
         return _host_p2p(hg, x, active, sr, direction=direction,
                          y_init=y_init, ecap=ecap, pol=pol)
@@ -975,14 +1000,14 @@ def host_traverse(
                                reverse=False, y_init=y_init, pol=pol)
         return y, st._replace(messages=mf)
 
-    use_pull = bool(beamer_use_pull(
+    use_pull = bool(host_read(beamer_use_pull(
         mf,
         frontier_edge_mass(hg.out_degree, unexplored),
         jnp.sum(active.astype(jnp.int32)),
         hg.n,
         alpha=pol.alpha,
         beta=pol.beta,
-    ))
+    )))
     if use_pull:
         y, st = _host_dispatch(hg, xm, unexplored, sr, direction="in",
                                reverse=False, y_init=y_init, pol=pol)
@@ -1054,7 +1079,7 @@ def run_program_host(
 
     io = IOStats.zero()
     it = 0
-    done = bool(prog.converged(sg, state, None)) \
+    done = bool(host_read(prog.converged(sg, state, None))) \
         if prog.check_initial_convergence else False
     if resume and ctx is not None:
         hit = ctx.try_restore(sg, state)
@@ -1070,16 +1095,17 @@ def run_program_host(
     try:
         while not done and it < budget:
             maybe_fail(_plan, it)
-            fr = frontier_fn(state)
-            gathered, st = prog.gather(sg, state, fr, pol)
-            state, activated = apply_fn(state, gathered)
-            state, st_act = prog.activate(sg, state, pol)
-            io = io + st
-            if st_act is not None:
-                io = io + st_act
-            io = io._replace(supersteps=io.supersteps + 1)
+            with jax.profiler.TraceAnnotation("graphyti.superstep", it=it):
+                fr = frontier_fn(state)
+                gathered, st = prog.gather(sg, state, fr, pol)
+                state, activated = apply_fn(state, gathered)
+                state, st_act = prog.activate(sg, state, pol)
+                io = io + st
+                if st_act is not None:
+                    io = io + st_act
+                io = io._replace(supersteps=io.supersteps + 1)
+                done = bool(host_read(prog.converged(sg, state, activated)))
             it += 1
-            done = bool(prog.converged(sg, state, activated))
             finished = done or it >= budget
             if ctx is not None and ctx.due(it, finished):
                 act = frontier_fn(state).active

@@ -11,8 +11,8 @@ This module's model: O(n) dense vertex-state vectors resident in fast memory,
                      the frontier intersects its contiguous major-vertex range.
 
 Every fetch/skip decision is counted (`IOStats`), which is what lets the
-benchmarks reproduce the paper's I/O figures (Fig. 2, 5, 6) rather than just
-its algorithm outputs.
+chip benchmark (``python3 bench/run.py``) report the I/O the paper's
+figures plot (Fig. 2, 5, 6) rather than just its algorithm outputs.
 
 Layouts:
   * ``sorted_by='src'`` — *push* store. Active sources send contributions
@@ -110,7 +110,7 @@ class IOStats(NamedTuple):
       every unbatched run.  Not an accumulating counter: divide any other
       field by ``max(queries, 1)`` for the per-query amortized cost (e.g.
       ``host_bytes / queries`` is the host-link bytes each query paid —
-      the number `benchmarks/bench_multisource.py` sweeps against Q).
+      the number batching exists to shrink).
 
     All counters are int32 (JAX's default integer without x64), so each
     wraps at 2^31 of its unit — ~2 GiB for ``bytes_moved``, ~2.1e9 edge
@@ -520,9 +520,11 @@ def sem_spmv(
         return (y, st), None
 
     w_arr = store.w if has_w else jnp.zeros_like(store.major, dtype=jnp.float32)
-    (y, st), _ = jax.lax.scan(
-        body, (y0, IOStats.zero()), (store.major, store.minor, w_arr, store.lo, store.hi)
-    )
+    with jax.named_scope("graphyti.chunk_scan"):
+        (y, st), _ = jax.lax.scan(
+            body, (y0, IOStats.zero()),
+            (store.major, store.minor, w_arr, store.lo, store.hi),
+        )
     return y[:n], st
 
 
@@ -589,8 +591,9 @@ def compact_spmv(
             y, m = fetch(y, major, minor, w, valid)
             return (y, msgs + m), None
 
-        (y, msgs), _ = jax.lax.scan(body, (y0, jnp.zeros((), jnp.int32)),
-                                    (ids, step_valid))
+        with jax.named_scope("graphyti.chunk_scan"):
+            (y, msgs), _ = jax.lax.scan(
+                body, (y0, jnp.zeros((), jnp.int32)), (ids, step_valid))
         st = IOStats(
             # requests/records/skips are per-chunk facts independent of the
             # execution order — computed vectorized over the activity bitmap

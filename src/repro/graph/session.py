@@ -20,8 +20,8 @@ single :class:`~repro.core.ExecutionPolicy`.
 
 The façade adds no execution layer of its own: methods call
 :func:`~repro.core.run_program` on the cached views, so a façade call
-compiles to exactly the same XLA as a hand-driven program
-(``benchmarks/bench_api.py`` holds the <2% overhead gate).
+compiles to exactly the same XLA as a hand-driven program (the chip
+benchmark, ``python3 bench/run.py``, times façade calls).
 """
 from __future__ import annotations
 

@@ -205,15 +205,17 @@ def spmv_pallas(
         scratch_shapes=[pltpu.VMEM((Bd, K), jnp.float32)],
     )
 
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_dst_blocks, Bd, K), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-        ),
-        interpret=interpret,
-    )(dbid, sbid, first, last, accum, act, tiles, x_blocks)
+    with jax.named_scope("graphyti.tile_kernel"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((n_dst_blocks, Bd, K),
+                                           jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+            ),
+            interpret=interpret,
+        )(dbid, sbid, first, last, accum, act, tiles, x_blocks)
 
 
 def _kernel_plus_times_compact(
@@ -332,12 +334,14 @@ def spmv_pallas_compact(
         scratch_shapes=[pltpu.VMEM((Bd, K), jnp.float32)],
     )
 
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_dst_blocks, Bd, K), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-        ),
-        interpret=interpret,
-    )(perm, dbid, sbid, first, last, accum, nact, tiles, x_blocks)
+    with jax.named_scope("graphyti.tile_kernel"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((n_dst_blocks, Bd, K),
+                                           jnp.float32),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+            ),
+            interpret=interpret,
+        )(perm, dbid, sbid, first, last, accum, nact, tiles, x_blocks)

@@ -378,7 +378,7 @@ def x_fetch_count(sbid: jnp.ndarray, act_tile: jnp.ndarray) -> jnp.ndarray:
     the counter charges the schedule so the full and compacted executions
     of one (order, frontier) pair report the same number, and only the
     tile ORDER moves it.  This is the quantity ``tile_order`` exists to
-    minimize (``benchmarks/bench_tile_order.py`` sweeps it).
+    minimize (``tests/test_tile_order.py`` counts it).
     """
     T = int(sbid.shape[0])
     act = act_tile.astype(bool)

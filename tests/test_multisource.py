@@ -19,7 +19,7 @@ pure I/O optimization — **bitwise invisible** in every answer.
     1-D union, so the recovery schema is width-independent).
   * **Amortization** — under ``residency='host'`` the per-query host-link
     bytes drop: Q batched queries move far fewer bytes than Q sequential
-    runs (the claim ``benchmarks/bench_multisource.py`` quantifies).
+    runs.
   * **Queue composition** — ``shard_sources(batch=Q)`` payloads feed
     batched passes whose canonical-tid merge stays death-invariant.
 """
@@ -212,8 +212,7 @@ class TestAmortization:
             for q in range(len(SOURCES))
         )
         # one streamed tile serves all Q queries: the batched sweep's
-        # host-link traffic must be well under the sequential total (the
-        # >= 4x-at-Q=8 claim lives in benchmarks/bench_multisource.py).
+        # host-link traffic must be well under the sequential total.
         assert int(batched.iostats.host_bytes) * 2 < seq
 
 
