@@ -89,6 +89,7 @@ from .sem import (
     _pad_y_init,
     _store_record_bytes,
     build_store_arrays,
+    flatten_single_lane,
     frontier_edge_mass,
     pad_state,
 )
@@ -422,9 +423,11 @@ def _chunk_batch_fn(sr: Semiring, n: int, gather_on_major: bool,
     """Jitted scan over one staging batch of chunks — the same per-chunk
     fetch (:func:`~repro.core.sem._make_fetch`) the device paths run, so
     each live chunk's scatter is bitwise the device scatter.  ``valid``
-    masks padding slots (whole-chunk no-ops).  Its scan runs under the
-    ``graphyti.chunk_scan`` scope, the name of the device residency's
-    chunk scans too."""
+    masks padding slots (whole-chunk no-ops).  ``y``/``xp`` arrive as
+    :func:`_stream_chunks` leaves them: 1-D for a single-lane state
+    (:func:`~repro.core.sem.flatten_single_lane`, which the device scans
+    apply too).  Its scan runs under the ``graphyti.chunk_scan`` scope,
+    the name of the device residency's chunk scans too."""
 
     def run(y, msgs, xp, active, major, minor, w, valid):
         fetch = _make_fetch(sr, xp, active, n, gather_on_major, has_w)
@@ -488,12 +491,14 @@ def _stream_chunks(hg: HostGraph, store: HostChunkStore, x, active,
                    pol: ExecutionPolicy):
     """The scan/compact backends' host execution: numpy activity plan ->
     ascending live chunk ids -> ``stream_buffer``-sized batches,
-    double-buffered host->device."""
+    double-buffered host->device.  A single-lane state is flattened here,
+    once per superstep, so the batch kernel compiles for a 1-D carry."""
     n, S = store.n, store.chunk_size
     C = store.num_chunks
     gather_on_major = (store.sorted_by == "src") != reverse
     has_w = store.w is not None
-    xp = pad_state(x, sr)
+    xf, y_init, restore = flatten_single_lane(x, y_init)
+    xp = pad_state(xf, sr)
     y = _pad_y_init(sr, xp, y_init, n)
     msgs = jnp.zeros((), jnp.int32)
     B = int(pol.stream_buffer)
@@ -505,7 +510,8 @@ def _stream_chunks(hg: HostGraph, store: HostChunkStore, x, active,
         per_chunk = prefix[store.hi + 1] - prefix[store.lo]
         live = np.flatnonzero(per_chunk > 0)
         batches = [live[i:i + B] for i in range(0, len(live), B)]
-        span.set_metadata(live=int(live.size), units=C)
+        span.set_metadata(live=int(live.size), units=C,
+                          flat=int(xf.ndim < x.ndim))
 
     kern = _chunk_batch_fn(sr, n, gather_on_major, has_w)
     # Unweighted stores ship no weight column; the kernel's w operand is a
@@ -567,7 +573,7 @@ def _stream_chunks(hg: HostGraph, store: HostChunkStore, x, active,
         host_bytes=_wrap_i32(host_bytes),
         retries=_wrap_i32(retr),
     )
-    return y[:n], st
+    return restore(y[:n]), st
 
 
 def _tile_encoding(sr: Semiring, weighted: bool) -> str:

@@ -26,6 +26,7 @@ path) and by :func:`repro.core.engine.flat_spmv` (the in-memory baseline).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -46,6 +47,7 @@ __all__ = [
     "chunk_activity",
     "compact_spmv",
     "device_graph",
+    "flatten_single_lane",
     "frontier_edge_mass",
     "pad_state",
     "pow2_buckets",
@@ -352,6 +354,34 @@ def pad_state(x: jnp.ndarray, sr: Semiring) -> jnp.ndarray:
     return jnp.concatenate([x, pad_row], axis=0)
 
 
+def flatten_single_lane(x: jnp.ndarray, y_init: Optional[jnp.ndarray] = None):
+    """Carry a state with exactly one trailing lane as a 1-D vector.
+
+    A ``[n, 1]`` state (one BFS key, one query column) and the ``[n]``
+    state PageRank keeps hold the same numbers, but the TPU tiles
+    ``[n + 1, 1]`` unlike the ``[n + 1]`` its scatter wants: a chunk scan
+    carrying the 2-D form relayouts the whole carry (and the gathered
+    ``x``) on every chunk step.  The chunk scans therefore flatten such a
+    state once per call, run the 1-D scan, and restore the shape once on
+    exit.  The decision rests on ``x``'s shape alone: a state with no
+    trailing axis or with more than one lane passes through unchanged.
+
+    Returns ``(x, y_init, restore)``: ``x`` and ``y_init`` flattened to
+    ``[n]`` where ``x.shape[1:]`` has product 1, and ``restore`` mapping a
+    ``[rows]`` result back to ``[rows] + x.shape[1:]`` (the identity
+    otherwise).  Host and device residencies both go through here, so
+    both scan with the same per-chunk fetch, bitwise.
+    """
+    lanes = x.shape[1:]
+    if not lanes or math.prod(lanes) != 1:
+        return x, y_init, lambda y: y
+
+    def flat(a):
+        return None if a is None else a.reshape(a.shape[0])
+
+    return flat(x), flat(y_init), lambda y: y.reshape(y.shape[:1] + lanes)
+
+
 def _active_prefix(active: jnp.ndarray) -> jnp.ndarray:
     """prefix[i] = #active in [0, i); length n+2 so sentinel hi=n is safe."""
     c = jnp.cumsum(active.astype(jnp.int32))
@@ -471,7 +501,8 @@ def sem_spmv(
     through.
 
     Args:
-      x: float/bool[n, ...] vertex state (unpadded; padded internally).
+      x: float/bool[n, ...] vertex state (unpadded; padded internally;
+        one trailing lane scans as ``[n]``, see :func:`flatten_single_lane`).
       active: bool[n] frontier over the *major* vertex.
       y_init: optional initial output (n rows); defaults to the semiring
         identity.
@@ -482,6 +513,7 @@ def sem_spmv(
       FlashGraph eliding SSD page reads for inactive vertex ranges.
     """
     n = store.n
+    x, y_init, restore = flatten_single_lane(x, y_init)
     xp = pad_state(x, sr)
     prefix = _active_prefix(active)
     y0 = _pad_y_init(sr, xp, y_init, n)
@@ -525,7 +557,7 @@ def sem_spmv(
             body, (y0, IOStats.zero()),
             (store.major, store.minor, w_arr, store.lo, store.hi),
         )
-    return y[:n], st
+    return restore(y[:n]), st
 
 
 def compact_spmv(
@@ -567,6 +599,7 @@ def compact_spmv(
     n = store.n
     C = store.num_chunks
     cap = max(1, min(int(chunk_cap), C))
+    x, y_init, restore = flatten_single_lane(x, y_init)
     xp = pad_state(x, sr)
     prefix = _active_prefix(active)
     y0 = _pad_y_init(sr, xp, y_init, n)
@@ -612,12 +645,14 @@ def compact_spmv(
         return y[:n], st
 
     if assume_fits:
-        return compact_branch(None)
+        y, st = compact_branch(None)
+    else:
+        def full_branch(_):
+            return sem_spmv(store, x, active, sr, y_init, reverse=reverse)
 
-    def full_branch(_):
-        return sem_spmv(store, x, active, sr, y_init, reverse=reverse)
-
-    return jax.lax.cond(n_act_chunks <= cap, compact_branch, full_branch, None)
+        y, st = jax.lax.cond(n_act_chunks <= cap, compact_branch,
+                             full_branch, None)
+    return restore(y), st
 
 
 def p2p_spmv(

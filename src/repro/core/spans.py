@@ -15,7 +15,9 @@ Host spans (``core/residency.py``, ``core/program.py``,
 * ``graphyti.segment`` (``stop``) — one re-bind of the device driver's
   traced segment loop;
 * ``graphyti.plan`` (``live``, ``units``) — a host executor's numpy plan:
-  activity mirror, live ids, batch list; also the p2p density gate;
+  activity mirror, live ids, batch list; also the p2p density gate; the
+  chunk executor's plan adds ``flat``, 1 where its scan carries a
+  single-lane state as a 1-D vector (``sem.flatten_single_lane``);
 * ``graphyti.stage`` (``bytes``, ``units``) — one staging batch: host
   gather plus ``device_put``, retries included; ``bytes`` is the exact
   count that ``IOStats.host_bytes`` adds before its int32 wrap;

@@ -73,20 +73,26 @@ def bfs_levels(g, key):
     return level
 
 
-def streamed_bytes(g, levels, supersteps):
-    """Bytes a host BFS ships: per superstep, the out-store chunks (CHUNK
-    edges in CSR order) whose source range holds a frontier vertex, in
-    batches of BUFFER chunks padded to full size; a chunk slot is its
-    int32 source and destination columns, plus one ``valid`` byte."""
+def live_chunks(g, levels, supersteps):
+    """Per superstep of a host BFS, the out-store chunks (CHUNK edges in
+    CSR order) whose source range holds a frontier vertex."""
     src = np.repeat(np.arange(g.n), np.diff(g.indptr))
     starts = np.arange(0, g.m, CHUNK)
     lo, hi = src[starts], src[np.minimum(starts + CHUNK, g.m) - 1]
-    total = 0
+    out = []
     for t in range(supersteps):
         on = np.flatnonzero(levels == t)
-        live = sum(bool(np.any((on >= a) & (on <= b))) for a, b in zip(lo, hi))
-        total += -(-live // BUFFER) * BUFFER * (CHUNK * 8 + 1)
-    return total
+        out.append(sum(bool(np.any((on >= a) & (on <= b)))
+                       for a, b in zip(lo, hi)))
+    return out
+
+
+def streamed_bytes(g, levels, supersteps):
+    """Bytes a host BFS ships: its live chunks, in batches of BUFFER
+    chunks padded to full size; a chunk slot is its int32 source and
+    destination columns, plus one ``valid`` byte."""
+    return sum(-(-live // BUFFER) * BUFFER * (CHUNK * 8 + 1)
+               for live in live_chunks(g, levels, supersteps))
 
 
 def test_host_bfs_spans(graph, tmp_path):
@@ -104,8 +110,21 @@ def test_host_bfs_spans(graph, tmp_path):
     plans = [s for name, s in events if name == "graphyti.plan"]
     assert len(plans) == steps
     assert all(s["units"] == -(-graph.m // CHUNK) for s in plans)
+    live = live_chunks(graph, bfs_levels(graph, 3), steps)
+    assert [s["live"] for s in plans] == live
+    # One key: the [n, 1] state scans as a 1-D vector in every superstep.
+    assert all(s["flat"] == 1 for s in plans)
     names = {name for name, _ in events}
     assert {"graphyti.sync", "graphyti.enqueue"} <= names
+    # Two lanes (one key twice, so neither retires early) keep their 2-D
+    # carry; the plan's live and units counts are the same.
+    g.bfs([3, 3], policy=HOST)
+    res2, events2 = traced(tmp_path / "k2", lambda: g.bfs([3, 3], policy=HOST))
+    assert int(res2.supersteps) == steps
+    plans2 = [s for name, s in events2 if name == "graphyti.plan"]
+    assert [s["live"] for s in plans2] == live
+    assert all(s["units"] == -(-graph.m // CHUNK) for s in plans2)
+    assert all(s["flat"] == 0 for s in plans2)
 
 
 @pytest.mark.parametrize("residency", ["host", "device"])
