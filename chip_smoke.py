@@ -1,9 +1,11 @@
 """Bring-up smoke: the graph path on one TPU chip, checked against numpy.
 
-    python chip_smoke.py [--scale 21] [--tile-scale 14] [--seed 0]
+    python chip_smoke.py [--scale 21] [--tile-scale 14] [--sssp-scale 18]
+                         [--seed 0]
 
-Three phases run through the public ``repro.Graph`` entry points, each
-checked against a plain numpy reference defined in this file:
+Four phases run through the public ``repro.Graph`` entry points, each
+checked against a plain reference (numpy, defined in this file; scipy's
+Dijkstra of ``bench/reference_sssp.py`` for SSSP):
 
   (a) device-resident: an RMAT graph (Graph500 initiator, edge factor 16,
       symmetrized) at ``--scale``; an 8-root batched BFS under
@@ -12,7 +14,11 @@ checked against a plain numpy reference defined in this file:
       equal to phase (a), with no edge bytes on the device;
   (c) the compiled Pallas tile kernels: RMAT at ``--tile-scale`` under
       ``backend='blocked'`` and ``'blocked_compact'``, compared with
-      ``'scan'``.
+      ``'scan'``;
+  (d) weighted shortest paths: RMAT at ``--sssp-scale`` with seeded
+      weights uniform in [0, 1), ``sssp()`` from two roots under its
+      default policy (the point-to-point, dense and ``min_plus`` arms), and
+      both roots as one batched call, bitwise equal to the single ones.
 
 Earlier lines report per-phase wall time (compilation included),
 ``memory_report()`` and the device's ``peak_bytes_in_use``.  The last line
@@ -32,7 +38,7 @@ from pathlib import Path
 import numpy as np
 
 ROOT = Path(__file__).resolve().parent
-sys.path.insert(0, str(ROOT / "src"))
+sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 # The target is RMAT scale 22 (about 128M directed edges, 3.1 GB of chunk
 # stores and CSR).  On a one-chip v5e host that run took about 1,170 s, too
@@ -43,6 +49,8 @@ UNREACHED = np.iinfo(np.int32).max
 DAMPING = 0.85
 PR_TOL = 1e-3
 N_ROOTS = 8
+# float32 path sums of a few dozen hops against float64 Dijkstra.
+SSSP_REL_ERR = 1e-5
 
 
 # --------------------------------------------------------------------------
@@ -127,9 +135,24 @@ def _check_pagerank(name: str, host, values, log) -> np.ndarray:
     return rank
 
 
-def run_phases(scale: int, *, tile_scale: int = 14, seed: int = 0,
-               compiled: bool = True, log=print) -> dict:
-    """Run phases (a)-(c); raise ``AssertionError`` on any mismatch.
+def _check_sssp(name: str, got, ref: np.ndarray) -> float:
+    """The widest relative error of ``got`` from Dijkstra's ``ref``;
+    reachability and zero distances exactly."""
+    from bench import reference_sssp
+
+    if reference_sssp.reach_wrong(got, ref):
+        raise AssertionError(f"{name}: SSSP reaches other vertices than "
+                             "Dijkstra")
+    err = reference_sssp.max_rel_err(got, ref)
+    if not err <= SSSP_REL_ERR:
+        raise AssertionError(f"{name}: SSSP distances {err!r} from Dijkstra "
+                             f"(limit {SSSP_REL_ERR!r})")
+    return err
+
+
+def run_phases(scale: int, *, tile_scale: int = 14, sssp_scale: int = 18,
+               seed: int = 0, compiled: bool = True, log=print) -> dict:
+    """Run phases (a)-(d); raise ``AssertionError`` on any mismatch.
 
     ``compiled=True`` also requires the blocked backends' lowered program
     to hold the Pallas kernel as a TPU custom call (no interpret mode)."""
@@ -238,6 +261,33 @@ def run_phases(scale: int, *, tile_scale: int = 14, seed: int = 0,
     log(f"(c) {bg.num_tiles} tiles of {bg.bd}x{bg.bs}: "
         f"{out['c_seconds']:.1f} s; memory_report "
         f"{json.dumps(out['c_memory'])}; peak_bytes_in_use {_peak_bytes()}")
+
+    # ---- (d) weighted shortest paths -----------------------------------
+    from bench import reference_sssp
+
+    t0 = time.perf_counter()
+    sh = rmat(sssp_scale, edge_factor=16, seed=seed, symmetrize=True)
+    weights = np.random.default_rng(seed).random(sh.m, dtype=np.float32)
+    sg = repro.Graph.from_csr(sh.indptr, sh.indices, weights=weights)
+    matrix = reference_sssp.weighted_csr(sh.indptr, sh.indices, weights)
+    s_roots = _pick_roots(sh, rng)[:2]
+    single = []
+    for root in s_roots:
+        tb = time.perf_counter()
+        res = sg.sssp(int(root))
+        single.append(np.asarray(res.values))
+        err = _check_sssp(f"(d) sssp {root}", single[-1],
+                          reference_sssp.distances(matrix, root))
+        log(f"(d) sssp root {root}: {int(res.supersteps)} supersteps, "
+            f"{int(res.state.improved)} improvements, "
+            f"{time.perf_counter() - tb:.1f} s, max rel err {err!r}")
+    both = np.asarray(sg.sssp(s_roots).values)
+    if not np.array_equal(both, np.stack(single, axis=1)):
+        raise AssertionError("(d) batched sssp is not bitwise equal to the "
+                             "single-root runs")
+    out["d_seconds"] = time.perf_counter() - t0
+    log(f"(d) sssp at rmat scale {sssp_scale}, m={sh.m}: "
+        f"{out['d_seconds']:.1f} s; peak_bytes_in_use {_peak_bytes()}")
     return out
 
 
@@ -245,6 +295,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--scale", type=int, default=DEFAULT_SCALE)
     ap.add_argument("--tile-scale", type=int, default=14)
+    ap.add_argument("--sssp-scale", type=int, default=18)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
@@ -266,7 +317,8 @@ def main(argv=None) -> int:
     print(f"# device: {dev.platform} {dev.device_kind} x{len(jax.devices())}, "
           f"jax {jax.__version__}", flush=True)
     try:
-        run_phases(args.scale, tile_scale=args.tile_scale, seed=args.seed,
+        run_phases(args.scale, tile_scale=args.tile_scale,
+                   sssp_scale=args.sssp_scale, seed=args.seed,
                    log=lambda s: print(f"# {s}", flush=True))
     except Exception:  # noqa: BLE001 - any failed phase fails the smoke
         traceback.print_exc()

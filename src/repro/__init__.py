@@ -4,10 +4,11 @@ The public API is two layers:
 
   * :class:`repro.Graph` — the session façade: build once
     (``from_edges`` / ``from_csr``), run the library
-    (``.bfs() .pagerank() .betweenness() .coreness() .diameter()
-    .triangles() .louvain()``) or your own algorithm (``.run(program)``),
-    every call returning a :class:`~repro.core.ProgramResult` and driven
-    by one :class:`~repro.core.ExecutionPolicy`.
+    (``.bfs() .sssp() .pagerank() .betweenness() .coreness()
+    .diameter() .triangles() .louvain()``) or your own algorithm
+    (``.run(program)``), every call returning a
+    :class:`~repro.core.ProgramResult` and driven by one
+    :class:`~repro.core.ExecutionPolicy`.
   * :class:`repro.VertexProgram` + :func:`repro.run_program` — the
     extension point: ~30 lines of vertex logic inherit the full engine
     (push/pull direction optimization, density-adaptive dispatch, blocked
@@ -31,6 +32,7 @@ from .core import (
     run_program,
     run_supervised,
 )
+from .algs.sssp import SSSPProgram
 from .graph.session import Graph
 
 __all__ = [
@@ -43,6 +45,7 @@ __all__ = [
     "PolicyError",
     "ProgramResult",
     "ResidencyError",
+    "SSSPProgram",
     "VertexProgram",
     "WorkQueue",
     "run_program",

@@ -25,6 +25,7 @@ from .pagerank import (
     pagerank_pull,
     pagerank_push,
 )
+from .sssp import SSSPProgram
 from .triangles import TriangleResult, count_triangles, triangles_blocked_mxu
 
 __all__ = [
@@ -37,6 +38,7 @@ __all__ = [
     "LouvainResult",
     "PageRankPullProgram",
     "PageRankPushProgram",
+    "SSSPProgram",
     "TriangleResult",
     "bc_fused",
     "bc_multisource",

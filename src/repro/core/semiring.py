@@ -82,12 +82,19 @@ class Semiring:
         return jnp.where(active, x, jnp.asarray(self.identity, x.dtype))
 
 
+def _per_row(w, xv):
+    """Edge weights ``w`` (one per gathered row) broadcast over the
+    trailing query lanes of ``xv`` (``[E]`` against ``[E, K]``)."""
+    w = jnp.asarray(w)
+    return w.reshape(w.shape + (1,) * (jnp.ndim(xv) - w.ndim))
+
+
 def _times(xv, w):
-    return xv if w is None else xv * w
+    return xv if w is None else xv * _per_row(w, xv)
 
 
 def _plus(xv, w):
-    return xv if w is None else xv + w
+    return xv if w is None else xv + _per_row(w, xv)
 
 
 def _ident(xv, w):

@@ -31,6 +31,11 @@ direction arms); ``graphyti.dense``, ``.compact`` and ``.p2p`` (the density
 arms); ``graphyti.chunk_scan`` (the per-chunk scan of ``sem_spmv`` and
 ``compact_spmv`` and the host batch kernel); ``graphyti.tile_kernel`` (the
 blocked Pallas calls).
+
+Program counters ride in the program's state and come back in
+``ProgramResult.state``: ``SSSPProgram``'s ``improved`` (int32) sums, over
+all supersteps, the (vertex, lane) distances that fell — the
+label-correcting redundancy of a frontier Bellman-Ford.
 """
 from __future__ import annotations
 

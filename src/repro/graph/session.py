@@ -56,6 +56,7 @@ from ..algs.pagerank import (
     PageRankPushProgram,
     PersonalizedPageRankProgram,
 )
+from ..algs.sssp import SSSPProgram
 from ..algs.triangles import TriangleResult, count_triangles
 from . import csr
 
@@ -467,9 +468,40 @@ class Graph:
         IOStats field divided by ``K`` is the per-query amortized cost.
         Values are bitwise-identical to K independent runs either way.
         """
+        return self._search(BFSProgram(), sources, policy=policy,
+                            max_supersteps=max_supersteps,
+                            checkpoint=checkpoint, resume=resume)
+
+    def sssp(
+        self,
+        sources=0,
+        *,
+        policy: Optional[ExecutionPolicy] = None,
+        max_supersteps: Optional[int] = None,
+        checkpoint=None,
+        resume: bool = False,
+    ) -> ProgramResult:
+        """(Multi-source) weighted shortest paths by frontier Bellman-Ford
+        (:class:`~repro.algs.SSSPProgram`).  ``values``: float32
+        distances — ``[n]`` for a scalar source, ``[n, K]`` for K sources
+        (``inf`` where a lane never arrives).  Sources are handled as in
+        :meth:`bfs`; ``state.improved`` counts the distance decreases.
+
+        Needs a weighted graph (``weights=`` at build time): an
+        unweighted one raises ``ValueError``.
+        """
+        return self._search(SSSPProgram(), sources, policy=policy,
+                            max_supersteps=max_supersteps,
+                            checkpoint=checkpoint, resume=resume)
+
+    def _search(self, prog: VertexProgram, sources, *, policy,
+                max_supersteps, checkpoint, resume) -> ProgramResult:
+        """Run a per-source program (one lane per source): a scalar
+        source on :func:`run_program` with the lane dropped from
+        ``values``, ``[K]`` sources on the batched driver (or on
+        :func:`run_program` under a trace)."""
         scalar = np.ndim(sources) == 0
         seeds = jnp.atleast_1d(jnp.asarray(sources, jnp.int32))
-        prog = BFSProgram()
         driver = (run_program if scalar or under_trace(seeds)
                   else run_program_batched)
         res = driver(self._sem(policy, prog), prog, policy, seeds=seeds,

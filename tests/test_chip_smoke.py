@@ -20,11 +20,21 @@ def smoke():
 
 
 def test_phases_match_numpy_references(smoke):
-    out = smoke.run_phases(10, tile_scale=9, seed=1, compiled=False,
-                           log=lambda s: None)
+    out = smoke.run_phases(10, tile_scale=9, sssp_scale=9, seed=1,
+                           compiled=False, log=lambda s: None)
     assert out["b_memory"]["device_edge_total"] == 0
     assert out["a_memory"]["device_edge_total"] > 0
     assert out["tiles"] > 1
+    assert out["d_seconds"] > 0
+
+
+def test_sssp_check_refuses_a_wrong_distance(smoke):
+    ref = np.array([0.0, 0.5, 1.5, np.inf])
+    assert smoke._check_sssp("ok", ref.astype(np.float32), ref) == 0.0
+    with pytest.raises(AssertionError, match="from Dijkstra"):
+        smoke._check_sssp("off", np.array([0.0, 0.5, 1.6, np.inf]), ref)
+    with pytest.raises(AssertionError, match="other vertices"):
+        smoke._check_sssp("reach", np.array([0.0, 0.5, 1.5, 2.0]), ref)
 
 
 def test_bfs_reference_on_a_path(smoke):

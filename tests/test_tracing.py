@@ -12,7 +12,10 @@ Pinned down here, on the CPU:
   * the device scopes reach the compiled HLO's ``op_name`` metadata of the
     device superstep (through the driver's ``make_jaxpr`` /
     ``jaxpr_as_fun`` re-bind), the chunk scans, the host batch kernel and
-    the dispatch arms.
+    the dispatch arms, also the weighted ``min_plus`` arms of an SSSP
+    superstep;
+  * the SSSP program's ``improved`` counter is the number of distance
+    decreases a plain Bellman-Ford makes.
 """
 import glob
 
@@ -26,6 +29,8 @@ import repro
 from repro.core import (ExecutionPolicy, OR_AND, PLUS_TIMES, device_graph,
                         traverse)
 from repro.algs.pagerank import PageRankPullProgram
+from repro.algs.sssp import SSSPProgram
+from repro.graph import csr
 from repro.core.recovery import superstep_body
 from repro.core.residency import _chunk_batch_fn
 from repro.core.sem import IOStats, compact_spmv, sem_spmv
@@ -215,3 +220,55 @@ def test_scope_reaches_the_tile_kernel(graph):
         lambda x, a: traverse(sg, x, a, PLUS_TIMES, policy=pol),
         jnp.ones(small.n, jnp.float32), jnp.arange(small.n) < 8)
     assert "graphyti.tile_kernel" in names
+
+
+@pytest.fixture(scope="module")
+def weighted(graph):
+    w = np.random.default_rng(8).random(graph.m, dtype=np.float32)
+    return csr.Graph(n=graph.n, indptr=graph.indptr, indices=graph.indices,
+                     weights=w, in_indptr=graph.indptr,
+                     in_indices=graph.indices, in_weights=w)
+
+
+def test_sssp_scopes_reach_the_weighted_arms(weighted):
+    sg = device_graph(weighted, chunk_size=CHUNK)
+    prog = SSSPProgram()
+    # The default policy compiles no compacted arm; a chunk cap adds it.
+    pol = prog.prepare_policy(sg, ExecutionPolicy(chunk_cap=8))
+    body = superstep_body(sg, prog, pol)
+    carry = (prog.init(sg, jnp.asarray([3], jnp.int32)), IOStats.zero(),
+             jnp.zeros((), jnp.int32), jnp.zeros((), bool),
+             jnp.asarray(4, jnp.int32))
+
+    def seg(*c):
+        return jax.lax.while_loop(
+            lambda c: jnp.logical_and(~c[3], c[2] < c[4]), body, c)
+
+    flat, _ = jax.tree_util.tree_flatten(carry)
+    names = op_names(jaxpr_as_fun(jax.make_jaxpr(seg)(*carry)), *flat)
+    # The frontier phase only hands the state's fields on: no op of its
+    # own carries its scope.
+    for scope in ("gather", "apply", "p2p", "compact", "dense",
+                  "chunk_scan"):
+        assert f"graphyti.{scope}" in names, scope
+
+
+def test_sssp_improved_counts_the_decreases(weighted):
+    """A float32 Bellman-Ford in numpy, relaxing the same frontier in the
+    same order, makes the program's decreases one for one."""
+    src = np.repeat(np.arange(weighted.n), np.diff(weighted.indptr))
+    dist = np.full(weighted.n, np.inf, np.float32)
+    dist[3] = 0
+    frontier = dist == 0
+    decreases = 0
+    while frontier.any():
+        e = frontier[src]
+        relaxed = dist.copy()
+        np.minimum.at(relaxed, weighted.indices[e],
+                      dist[src[e]] + weighted.weights[e])
+        frontier = relaxed < dist
+        decreases += int(frontier.sum())
+        dist = relaxed
+    res = repro.Graph(weighted, chunk_size=CHUNK).sssp(3)
+    assert np.array_equal(np.asarray(res.values), dist)
+    assert int(res.state.improved) == decreases > weighted.n // 2
