@@ -39,6 +39,7 @@ from ..core import (
     run_program,
     traverse,
 )
+from ..core.engine import p2p_caps
 from ..core.semiring import PLUS_TIMES
 
 __all__ = ["CorenessProgram", "coreness"]
@@ -81,13 +82,11 @@ class CorenessProgram(VertexProgram):
     def prepare_policy(self, sg: SemGraph, policy: ExecutionPolicy):
         pol = policy.with_(direction="out")
         if self.messaging == "dense":
-            pol = pol.with_(switch_fraction=None)
-        else:
-            pol = pol.with_(
-                vcap=pol.vcap if pol.vcap is not None else sg.n,
-                ecap=pol.ecap if pol.ecap is not None else max(int(sg.m), 1),
-            )
-        return pol
+            return pol.with_(switch_fraction=None)
+        if pol.switch_fraction is None:  # no p2p arm, no caps to resolve
+            return pol
+        vcap, ecap, _ = p2p_caps(pol, sg.n, sg.m)
+        return pol.with_(vcap=vcap, ecap=ecap)
 
     def init(self, sg: SemGraph, seeds) -> CoreState:
         return CoreState(

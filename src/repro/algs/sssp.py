@@ -21,9 +21,9 @@ source, so ``K`` searches share one edge stream.
 The default policy is :class:`~repro.core.ExecutionPolicy`'s own: a
 superstep whose frontier carries at most a tenth of the edges takes the
 point-to-point gather at its static capacity (``vcap``/``ecap``, which
-default to ``n``/``m``), and a heavier one the dense chunk scan.  No
-compacted chunk scan is compiled unless the policy sets ``chunk_cap`` or
-``adaptive_cap``.
+default to ``n`` and to that tenth of ``m``), and a heavier one the dense
+chunk scan.  No compacted chunk scan is compiled unless the policy sets
+``chunk_cap`` or ``adaptive_cap``.
 """
 from __future__ import annotations
 

@@ -261,7 +261,9 @@ class ExecutionPolicy:
         smallest pow2 size fitting the live-unit count (``lax.switch``
         over the ~log2(cap) compiled buckets — no host round-trip).
       vcap / ecap: static vertex/edge capacities of the point-to-point
-        gather; ``None`` resolves to n / m (always exact, rarely optimal).
+        gather; ``None`` resolves to n and to the gate's edge bound
+        ``int(switch_fraction * m)`` (:func:`p2p_caps`): always exact,
+        since the arm never opens on more edges than that.
       switch_fraction: p2p engages when the frontier's edge mass is at
         most this fraction of m (and the caps fit).  ``None`` disables
         p2p entirely.
@@ -790,6 +792,24 @@ def _adaptive_p2p(sg, x, active, sr, *, direction, y_init, vcap, ecap,
     )
 
 
+def p2p_caps(pol: ExecutionPolicy, n: int, m: int) -> tuple[int, int, int]:
+    """``(vcap, ecap, gate)``: the p2p arm's static capacities and its gate.
+
+    The arm opens only when the frontier's edge mass is at most ``gate =
+    int(switch_fraction * m)``, so a ``None`` ``ecap`` resolves to that
+    bound (clamped to ``[1, m]``): every edge the gate admits has a slot,
+    and no slot is padding the gate rules out.  A ``None`` ``vcap``
+    resolves to ``n``, since degree-0 vertices add active rows without
+    edge mass.  Both residencies' dispatchers, ``Graph.memory_report`` and
+    the coreness program resolve through here, so host and device gather
+    with one lane count.  Requires ``pol.switch_fraction`` to be set.
+    """
+    gate = int(pol.switch_fraction * m)
+    vcap = pol.vcap if pol.vcap is not None else int(n)
+    ecap = pol.ecap if pol.ecap is not None else max(1, min(gate, int(m)))
+    return vcap, ecap, gate
+
+
 def _dispatch(sg, x, active, sr, *, direction, reverse, y_init, pol):
     """The density three-way (multicast / compact / p2p) for one direction.
 
@@ -800,12 +820,11 @@ def _dispatch(sg, x, active, sr, *, direction, reverse, y_init, pol):
         return _multicast(sg, x, active, sr, direction=direction,
                           reverse=reverse, y_init=y_init, pol=pol)
     deg = sg.out_degree if direction == "out" else sg.in_degree
-    vcap = pol.vcap if pol.vcap is not None else sg.n
-    ecap = pol.ecap if pol.ecap is not None else max(int(sg.m), 1)
+    vcap, ecap, gate = p2p_caps(pol, sg.n, sg.m)
     act_edges = frontier_edge_mass(deg, active)
     n_act = jnp.sum(active.astype(jnp.int32))
     use_p2p = (
-        (act_edges <= jnp.int32(pol.switch_fraction * sg.m))
+        (act_edges <= jnp.int32(gate))
         & (act_edges <= ecap)
         & (n_act <= vcap)
     )

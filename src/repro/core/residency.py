@@ -81,6 +81,7 @@ from .engine import (
     _check_blocked_semiring,
     batched_union_frontier,
     beamer_use_pull,
+    p2p_caps,
 )
 from .sem import (
     EDGE_RECORD_BYTES,
@@ -909,13 +910,12 @@ def _host_dispatch(hg, x, active, sr, *, direction, reverse, y_init, pol):
     if deg is None:  # no in view: let the multicast arm raise its error
         return _host_multicast(hg, x, active, sr, direction=direction,
                                reverse=reverse, y_init=y_init, pol=pol)
-    vcap = pol.vcap if pol.vcap is not None else hg.n
-    ecap = pol.ecap if pol.ecap is not None else max(int(hg.m), 1)
+    vcap, ecap, gate = p2p_caps(pol, hg.n, hg.m)
     with jax.profiler.TraceAnnotation("graphyti.plan"):
         act_edges = frontier_edge_mass(deg, active)
         n_act = jnp.sum(active.astype(jnp.int32))
         use_p2p = bool(host_read(
-            (act_edges <= jnp.int32(pol.switch_fraction * hg.m))
+            (act_edges <= jnp.int32(gate))
             & (act_edges <= ecap)
             & (n_act <= vcap)
         ))

@@ -40,6 +40,7 @@ from ..core import (
     run_program,
     run_program_batched,
 )
+from ..core.engine import p2p_caps
 from ..core.program import VertexProgram, under_trace
 from ..core.sem import _store_record_bytes, device_graph
 from ..core.semiring import PLUS_TIMES
@@ -339,8 +340,7 @@ class Graph:
             # piece (bitwise scatter parity needs the device's static lane
             # shape), so its single staged batch — not double-buffered —
             # can exceed the chunk/tile batch model.
-            ecap = (pol.ecap if pol.ecap is not None
-                    else max(int(self._host.m), 1))
+            _, ecap, _ = p2p_caps(pol, self.n, self._host.m)
             lane = 9 + (4 if self._host.weights is not None else 0)
             stream_buffer_bytes = max(stream_buffer_bytes, ecap * lane)
         hv = self._host_view

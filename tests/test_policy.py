@@ -352,3 +352,19 @@ def test_hybrid_spmv_policy_passthrough(sg):
     assert all(int(a) == int(b) for a, b in zip(st_p, st_k))
     y_f = flat_spmv(sg, x, act, PLUS_TIMES)
     assert bool(jnp.all(y_p == y_f))
+
+
+@pytest.mark.parametrize("sf", [0.0, 0.1, 0.125, 1.0, 2.0])
+def test_p2p_caps_default_to_the_gate(sf):
+    """A ``None`` ecap resolves to the gate's edge bound, clamped to
+    [1, m]; the gate is the device formula's ``jnp.int32(sf * m)``; ``None``
+    vcap is n; explicit caps pass through."""
+    from repro.core.engine import p2p_caps
+
+    for n, m in [(1, 0), (4, 1), (10, 7), (40, 64), (300, 1000)]:
+        vcap, ecap, gate = p2p_caps(ExecutionPolicy(switch_fraction=sf), n, m)
+        assert gate == int(jnp.int32(sf * m))
+        assert ecap == max(1, min(int(sf * m), m))
+        assert vcap == n
+        assert p2p_caps(ExecutionPolicy(switch_fraction=sf, vcap=3, ecap=5),
+                        n, m) == (3, 5, gate)

@@ -4,13 +4,16 @@ weights, a path, a grid with zero weights and a disconnected graph;
 bitwise the same across the dispatch arms, backends and residencies; a
 ``[K]`` call equal to K scalar calls; unit weights equal to BFS levels; and
 a clear refusal of a graph without weights."""
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import repro
 from bench import reference_sssp
 from repro.algs import UNREACHED
-from repro.core import ExecutionPolicy
+from repro.core import ExecutionPolicy, traverse
+from repro.core.engine import p2p_caps
+from repro.core.semiring import MIN_PLUS
 from repro.graph import csr
 from repro.graph.generators import rmat
 
@@ -155,3 +158,78 @@ def test_graph_without_weights_raises():
         g.sssp(0)
     with pytest.raises(ValueError, match="weights="):
         repro.run_program(g.device(), repro.SSSPProgram(), seeds=[0])
+
+
+def _gate_policy(mass, m, over):
+    """A policy whose p2p gate ``int(sf * m)`` is ``mass`` (or one edge
+    below it, when ``over``)."""
+    sf = ((mass - 1 if over else mass) + 0.5) / m
+    assert int(sf * m) == mass - int(over)
+    return ExecutionPolicy(switch_fraction=sf)
+
+
+@pytest.mark.parametrize("over", [False, True], ids=["at_gate", "over_gate"])
+def test_p2p_gate_edge_default_ecap(weighted, over):
+    """A frontier whose edge mass is exactly the gate takes the p2p arm at
+    the default ``ecap`` (the gate, no padding lane) and gives bitwise the
+    ``y`` and IOStats of ``ecap=m``; one edge over the gate falls to the
+    multicast arm."""
+    g = repro.Graph(weighted, chunk_size=CHUNK)
+    sg = g.device()
+    deg = np.diff(weighted.indptr)
+    active = np.zeros(weighted.n, bool)
+    active[np.flatnonzero(deg)[:12]] = True
+    mass = int(deg[active].sum())
+    assert 0 < mass < weighted.m // 4
+    x = np.where(active, np.random.default_rng(8).random(weighted.n), np.inf)
+    x, active = jnp.asarray(x, jnp.float32), jnp.asarray(active)
+
+    pol = _gate_policy(mass, weighted.m, over)
+    y, st = traverse(sg, x, active, MIN_PLUS, policy=pol)
+    p2p = traverse(sg, x, active, MIN_PLUS,
+                   policy=pol.with_(switch_fraction=1.0, ecap=weighted.m))
+    dense = traverse(sg, x, active, MIN_PLUS,
+                     policy=pol.with_(switch_fraction=None))
+    assert p2p[1] != dense[1]
+    want_y, want_st = dense if over else p2p
+    assert np.array_equal(np.asarray(y), np.asarray(want_y))
+    assert st == want_st
+    if not over:
+        # The parent's resolution, ecap = m, under the same gate.
+        y_m, st_m = traverse(sg, x, active, MIN_PLUS,
+                             policy=pol.with_(ecap=weighted.m))
+        assert np.array_equal(np.asarray(y), np.asarray(y_m))
+        assert st == st_m
+
+
+def test_sssp_default_ecap_equals_ecap_m(weighted):
+    g = repro.Graph(weighted, chunk_size=CHUNK)
+    base = g.sssp(7, policy=ExecutionPolicy(ecap=weighted.m))
+    res = g.sssp(7, policy=ExecutionPolicy())
+    assert np.array_equal(np.asarray(res.values), np.asarray(base.values))
+    assert int(res.supersteps) == int(base.supersteps)
+    assert int(res.state.improved) == int(base.state.improved)
+    assert res.iostats == base.iostats
+
+
+def test_sssp_host_device_parity_at_default_caps(weighted):
+    """Both residencies gather with the gate's lane count: the same
+    distances and IOStats (``host_bytes`` aside), and ``memory_report``
+    stages the p2p payload at ``gate`` lanes of 13 bytes."""
+    g = repro.Graph(weighted, chunk_size=CHUNK)
+    dev = g.sssp(7, policy=ExecutionPolicy())
+    host_pol = ExecutionPolicy(residency="host", stream_buffer=1)
+    host = g.sssp(7, policy=host_pol)
+    assert np.array_equal(np.asarray(host.values), np.asarray(dev.values))
+    assert int(host.state.improved) == int(dev.state.improved)
+    for f in dev.iostats._fields:
+        if f != "host_bytes":
+            assert int(getattr(host.iostats, f)) == int(
+                getattr(dev.iostats, f)), f
+    assert int(host.iostats.host_bytes) > 0
+
+    _, ecap, gate = p2p_caps(host_pol, weighted.n, weighted.m)
+    assert ecap == gate == int(0.1 * weighted.m)
+    chunk_batch = CHUNK * 12 + 1  # one chunk of 12 B records + flags
+    assert gate * 13 > chunk_batch
+    assert g.memory_report(host_pol)["stream_buffer_bytes"] == gate * 13
