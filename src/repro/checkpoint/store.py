@@ -32,8 +32,7 @@ Semantics a 1000-node deployment needs, implemented without external deps:
     principle: overlap slow-tier I/O with compute).
   * **Elastic restore** — arrays are saved with their *global* shapes;
     ``restore_checkpoint`` re-shards onto whatever mesh the restored job
-    runs with, so a job can restart on a smaller/larger pod count
-    (distributed/fault.py exercises this).
+    runs with, so a job can restart on a smaller/larger pod count.
   * **Retention** — ``keep`` bounds disk usage; the newest ``keep`` steps
     survive, plus any older step a surviving delta manifest references.
 """

@@ -8,9 +8,6 @@ The engine mirrors FlashGraph's execution model:
     backend, work-list capacities, push/pull direction, and all switch
     thresholds.  Algorithms pass a policy; the engine picks the cheapest
     execution per superstep.
-  * :func:`bsp_run` — the bare bulk-synchronous loop.  One iteration of the
-    ``lax.while_loop`` is one BSP superstep; the loop exits when the frontier
-    drains (all vertices inactive), i.e. the global barrier condition.
   * :func:`flat_spmv` — the *in-memory* baseline: one unchunked segment
     reduction over all m edges, no skipping, no counting.  This is what the
     "SEM achieves 80% of in-memory performance" claim is measured against.
@@ -165,7 +162,7 @@ backends, compaction, AND direction).
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -193,7 +190,6 @@ __all__ = [
     "as_policy",
     "batched_union_frontier",
     "beamer_use_pull",
-    "bsp_run",
     "hybrid_spmv",
     "flat_spmv",
     "spmv",
@@ -390,34 +386,6 @@ def beamer_use_pull(
     mu = unexplored_edges.astype(jnp.float32)
     nf = frontier_verts.astype(jnp.float32)
     return (mf * alpha > mu) & (nf * beta > float(n))
-
-
-def bsp_run(
-    step: Callable[[State], Tuple[State, jnp.ndarray]],
-    state0: State,
-    max_supersteps: int,
-) -> Tuple[State, jnp.ndarray]:
-    """Run ``step`` until it reports done or the superstep budget is hit.
-
-    ``step`` maps state -> (state, done:bool[]).  Returns the final state and
-    the number of supersteps executed.  The whole loop stays on device
-    (``lax.while_loop``), so there is no per-step host round-trip — the
-    analogue of FlashGraph keeping the BSP barrier inside the engine.
-    """
-
-    def cond(carry):
-        _, it, done = carry
-        return jnp.logical_and(~done, it < max_supersteps)
-
-    def body(carry):
-        state, it, _ = carry
-        state, done = step(state)
-        return state, it + 1, done
-
-    state, iters, _ = jax.lax.while_loop(
-        cond, body, (state0, jnp.zeros((), jnp.int32), jnp.zeros((), bool))
-    )
-    return state, iters
 
 
 def _select_blocked(sg: SemGraph, direction: str, reverse: bool):

@@ -36,12 +36,11 @@ recovery here covers all six paper algorithms plus every user
     :class:`CheckpointMismatchError` naming the mismatched component
     instead of silently resuming garbage.
 
-  * **Supervision** — :func:`run_supervised` ports the crash-injection
-    machinery of :mod:`repro.distributed.fault` (``FailurePlan`` /
-    ``DeviceFailure``) to the BSP loop: the driver raises at injected
-    supersteps, the supervisor replays from the newest checkpoint, and the
-    final result is gated bitwise against the uninterrupted run in
-    ``tests/test_recovery.py``.
+  * **Supervision** — :func:`run_supervised` drives the BSP loop through
+    crash injection (``FailurePlan`` / ``DeviceFailure``): the driver
+    raises at injected supersteps, the supervisor replays from the newest
+    checkpoint, and the final result is gated bitwise against the
+    uninterrupted run in ``tests/test_recovery.py``.
 """
 from __future__ import annotations
 
@@ -60,7 +59,6 @@ from jax.extend.core import jaxpr_as_fun
 import numpy as np
 
 from ..checkpoint import CheckpointManager, latest_step, load_extra
-from ..distributed.fault import DeviceFailure, FailurePlan
 from .engine import ExecutionPolicy
 from .sem import IOStats
 from .spans import host_read
@@ -74,6 +72,29 @@ __all__ = [
     "run_fingerprint",
     "run_supervised",
 ]
+
+
+class DeviceFailure(RuntimeError):
+    """Simulated loss of a device/node during a superstep."""
+
+
+@dataclasses.dataclass
+class FailurePlan:
+    """Injected events: {superstep: kind} with kind in 'crash' |
+    'sigkill'.  Each event fires once.
+
+    'crash' raises :class:`DeviceFailure` inside the process (the unwind
+    still runs — async checkpoint waits, context managers close).
+    'sigkill' (interpreted by :func:`maybe_fail`) kills the process with
+    an uncatchable signal — no unwind, no flush — modelling the OOM
+    killer / ``kill -9`` that multi-process fault tolerance must survive;
+    pair it with OS-level workers (``run_workers(processes=...)``) and
+    the :func:`~repro.core.workqueue.supervise_workers` chaos harness."""
+
+    events: dict
+
+    def pop(self, step: int) -> Optional[str]:
+        return self.events.pop(step, None)
 
 
 class CheckpointMismatchError(RuntimeError):

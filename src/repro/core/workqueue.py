@@ -62,6 +62,7 @@ from ..checkpoint import (
 )
 
 __all__ = [
+    "ChaosReport",
     "DurableWorkQueue",
     "durable_worker_loop",
     "Lease",
@@ -70,6 +71,7 @@ __all__ = [
     "WorkQueue",
     "run_workers",
     "shard_sources",
+    "supervise_workers",
 ]
 
 
@@ -730,6 +732,126 @@ def _durable_worker_main(root, tasks, cfg: dict, work_fn, worker_id: str,
                         faults=faults, poll=poll)
 
 
+# --------------------------------------------------------------------------
+# multi-process chaos supervision (OS workers over the durable queue)
+# --------------------------------------------------------------------------
+@dataclasses.dataclass
+class ChaosReport:
+    """What a :func:`supervise_workers` pool lived through.
+
+    ``stale_rejections`` aggregates the workers' refused late commits —
+    the chaos gate asserts it is >0 under stall injection (proof the
+    token check actually fired, not that the race never happened);
+    ``kills`` counts abnormal child exits (SIGKILL shows as -9)."""
+
+    num_workers: int = 0
+    spawned: int = 0
+    restarts: int = 0
+    kills: int = 0
+    completed: int = 0
+    stale_rejections: int = 0
+    leases: int = 0
+    dead_letters: list = dataclasses.field(default_factory=list)
+    finished: bool = False
+    log: list = dataclasses.field(default_factory=list)
+
+
+def supervise_workers(
+    queue,
+    work_fn: Callable[[Any], Any],
+    *,
+    num_workers: int = 3,
+    faults: Optional[dict] = None,
+    poll: float = 0.05,
+    max_spawns: Optional[int] = None,
+    timeout: float = 300.0,
+) -> ChaosReport:
+    """Run ``num_workers`` real OS processes over a ``DurableWorkQueue``
+    and keep the pool at strength until the queue finishes: any child
+    that exits abnormally (SIGKILL'd by a fault injection, OOM-killed,
+    crashed) is replaced with a fresh worker, which resumes from the
+    filesystem state alone — the supervisor holds NO sweep progress.
+
+    Spawn context, not fork: a forked child inherits XLA's runtime
+    threads mid-flight; spawned workers re-import and rebuild their own
+    sessions from the picklable task payloads.
+
+    ``max_spawns`` bounds total process creation (default: enough for
+    every task to fail ``max_attempts`` times); ``timeout`` bounds the
+    whole run — on expiry the pool is terminated and the report says
+    ``finished=False`` rather than hanging a test suite forever.
+
+    A TPU belongs to one process at a time, so a parent on the TPU
+    backend refuses to start workers (each would import JAX and wait for
+    the chip it holds); run the pool under ``JAX_PLATFORMS=cpu``.
+    """
+    import multiprocessing as mp
+
+    import jax
+
+    if not isinstance(queue, DurableWorkQueue):
+        raise TypeError("supervise_workers needs a DurableWorkQueue")
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "supervise_workers would start OS worker processes that import "
+            "JAX while this process holds the TPU; a chip serves one "
+            "process, so the workers would fail or hang.  Run the worker "
+            "pool with JAX_PLATFORMS=cpu, or run the sweep in-process."
+        )
+    ctx = mp.get_context("spawn")
+    cfg = {
+        "lease_timeout": queue.lease_timeout,
+        "max_attempts": queue.max_attempts,
+        "result_template": queue.result_template,
+    }
+    if max_spawns is None:
+        max_spawns = num_workers + queue.num_tasks * queue.max_attempts
+    rep = ChaosReport(num_workers=num_workers)
+
+    def spawn(wid: str):
+        p = ctx.Process(
+            target=_durable_worker_main,
+            args=(str(queue.root), queue.tasks, cfg, work_fn, wid,
+                  faults or {}, poll),
+            daemon=True,
+        )
+        p.start()
+        rep.spawned += 1
+        rep.log.append(f"spawned {wid} (pid {p.pid})")
+        return p
+
+    procs = {f"w{i}": spawn(f"w{i}") for i in range(num_workers)}
+    deadline = time.monotonic() + timeout
+    try:
+        while procs and time.monotonic() < deadline:
+            for wid, p in list(procs.items()):
+                p.join(timeout=poll)
+                if p.is_alive():
+                    continue
+                del procs[wid]
+                if p.exitcode != 0:
+                    rep.kills += 1
+                    rep.log.append(f"{wid} died (exit {p.exitcode})")
+                    if not queue.finished and rep.spawned < max_spawns:
+                        rep.restarts += 1
+                        nwid = f"{wid}r{rep.restarts}"
+                        procs[nwid] = spawn(nwid)
+                else:
+                    rep.log.append(f"{wid} exited clean")
+    finally:
+        for p in procs.values():
+            p.terminate()
+        for p in procs.values():
+            p.join(timeout=5.0)
+    rep.finished = queue.finished
+    rep.dead_letters = queue.dead_letters
+    for stats in queue.read_stats().values():
+        rep.completed += int(stats.get("completed", 0))
+        rep.stale_rejections += int(stats.get("stale", 0))
+        rep.leases += int(stats.get("leases", 0))
+    return rep
+
+
 def shard_sources(sources, shard_size: Optional[int] = None, *,
                   batch: Optional[int] = None) -> list:
     """Split a source vertex set into queue task payloads.
@@ -775,9 +897,9 @@ def run_workers(
     is N *real OS processes* (multiprocessing spawn context — fork is
     unsafe under a live XLA runtime) each running
     :func:`durable_worker_loop`, supervised and restarted on abnormal
-    exit by :func:`repro.distributed.fault.supervise_workers`; ``faults``
-    maps ``(tid, attempt)`` to ``"sigkill"``/stall injections and the
-    return value is that supervisor's ``ChaosReport``.  ``work_fn`` must
+    exit by :func:`supervise_workers`; ``faults`` maps ``(tid, attempt)``
+    to ``"sigkill"``/stall injections and the return value is that
+    supervisor's ``ChaosReport``.  ``work_fn`` must
     then be a module-level picklable callable.  The in-process simulation
     below is unchanged and remains the deterministic fast path.
 
@@ -801,8 +923,6 @@ def run_workers(
                 "processes= needs a DurableWorkQueue: OS workers share "
                 "progress through the filesystem, not this process's heap"
             )
-        from ..distributed.fault import supervise_workers
-
         return supervise_workers(
             queue, work_fn,
             num_workers=int(processes) if processes is not True else 3,

@@ -1,12 +1,9 @@
-"""Pallas TPU kernels for the two compute hot-spots (DESIGN.md §2).
+"""Pallas TPU kernel for the graph engine's compute hot-spot.
 
   spmv/        blocked-CSR semiring SpMV with frontier block skipping — the
                SEM "fetch edge chunk, combine with neighbor state" hot loop.
-  decode_attn/ KV-block-streaming decode attention with online softmax and
-               window/length block skipping — the SEM discipline applied to
-               LM serving.
 
-Each package ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
+The package ships kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 wrapper) and ref.py (pure-jnp oracle); tests sweep shapes/dtypes in
 interpret mode against the oracle.
 """

@@ -28,7 +28,7 @@ from repro.core import (
     run_workers,
     shard_sources,
 )
-from repro.distributed.fault import supervise_workers
+from repro.core.workqueue import supervise_workers
 from repro.graph.generators import rmat
 
 pytestmark = pytest.mark.kernel

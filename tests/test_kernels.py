@@ -7,7 +7,6 @@ import pytest
 pytestmark = pytest.mark.kernel
 
 from repro.graph.generators import cycle_graph, erdos_renyi, rmat, star_graph
-from repro.kernels.decode_attn import decode_attention, decode_attention_ref
 from repro.kernels.spmv import blocked_spmv, blocked_spmv_ref, build_blocked
 from repro.kernels.spmv.ref import coo_spmv_ref
 
@@ -69,76 +68,3 @@ def test_spmv_rmat_pagerank_iteration():
     y, _ = blocked_spmv(bg, x, None, interpret=True)
     y_ref = blocked_spmv_ref(bg, x, None)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), atol=1e-4, rtol=1e-4)
-
-
-# --------------------------------------------------------- decode_attn
-@pytest.mark.parametrize("kv,g", [(1, 8), (2, 4), (8, 1)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_decode_attn_matches_ref(kv, g, dtype):
-    rng = np.random.default_rng(kv * 10 + g)
-    B, hd, T = 2, 32, 256
-    h = kv * g
-    q = jnp.asarray(rng.normal(size=(B, h, hd)), dtype)
-    k = jnp.asarray(rng.normal(size=(B, T, kv, hd)), dtype)
-    v = jnp.asarray(rng.normal(size=(B, T, kv, hd)), dtype)
-    pos = jnp.broadcast_to(jnp.arange(T)[None], (B, T)).astype(jnp.int32)
-    cur = jnp.asarray([T // 3, T - 1], jnp.int32)
-    out = decode_attention(q, k, v, pos, cur, block_t=64, interpret=True)
-    ref = decode_attention_ref(q, k, v, pos, cur)
-    tol = 1e-4 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=tol, rtol=tol)
-
-
-@pytest.mark.parametrize("window", [32, 100])
-def test_decode_attn_window(window):
-    """Sliding window: only positions inside the window contribute, and
-    whole out-of-window blocks are skipped."""
-    rng = np.random.default_rng(window)
-    B, kv, g, hd, T = 1, 2, 2, 16, 512
-    q = jnp.asarray(rng.normal(size=(B, kv * g, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, T, kv, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, T, kv, hd)), jnp.float32)
-    pos = jnp.broadcast_to(jnp.arange(T)[None], (B, T)).astype(jnp.int32)
-    cur = jnp.asarray([T - 1], jnp.int32)
-    out = decode_attention(
-        q, k, v, pos, cur, window=window, block_t=64, interpret=True
-    )
-    ref = decode_attention_ref(q, k, v, pos, cur, window=window)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4, rtol=1e-4)
-
-
-def test_decode_attn_rotating_cache_slots():
-    """Rotating (mod-T) slot layout: the kernel keys masks on stored
-    positions, so scrambled slot order must not change the result."""
-    rng = np.random.default_rng(7)
-    B, kv, g, hd, T = 2, 1, 4, 16, 128
-    q = jnp.asarray(rng.normal(size=(B, kv * g, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, T, kv, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, T, kv, hd)), jnp.float32)
-    perm = rng.permutation(T)
-    base = np.broadcast_to(np.arange(T)[None], (B, T)).copy()
-    pos = jnp.asarray(base[:, perm], jnp.int32)
-    kp, vp = k[:, perm], v[:, perm]
-    cur = jnp.asarray([T - 1, T // 2], jnp.int32)
-    out = decode_attention(q, kp, vp, pos, cur, block_t=32, interpret=True)
-    ref = decode_attention_ref(q, k, v, jnp.asarray(base, jnp.int32), cur)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4, rtol=1e-4)
-
-
-def test_decode_attn_empty_slots():
-    """-1 (never written) slots are dead regardless of their k/v payload."""
-    rng = np.random.default_rng(9)
-    B, kv, g, hd, T = 1, 2, 2, 16, 128
-    q = jnp.asarray(rng.normal(size=(B, kv * g, hd)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, T, kv, hd)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, T, kv, hd)), jnp.float32)
-    pos_np = np.broadcast_to(np.arange(T)[None], (B, T)).copy()
-    pos_np[:, 64:] = -1  # half the cache never written
-    pos = jnp.asarray(pos_np, jnp.int32)
-    cur = jnp.asarray([T - 1], jnp.int32)
-    out = decode_attention(q, k, v, pos, cur, block_t=32, interpret=True)
-    # oracle over the live prefix only
-    ref = decode_attention_ref(
-        q, k[:, :64], v[:, :64], pos[:, :64], cur
-    )
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4, rtol=1e-4)

@@ -8,7 +8,6 @@ Invariants under test:
   * Semiring laws on the shipped semirings.
   * PageRank mass conservation; coreness peeling-order invariance.
   * Blocked SpMV tiling == COO ground truth for any (bd, bs).
-  * Packing keeps every token exactly once, in order.
 """
 import jax
 import jax.numpy as jnp
@@ -21,7 +20,6 @@ from hypothesis import given, settings, strategies as st
 from repro.core import device_graph, flat_spmv, sem_spmv
 from repro.core.sem import chunk_activity
 from repro.core.semiring import MIN_PLUS, OR_AND, PLUS_TIMES
-from repro.data import pack_documents
 from repro.graph.csr import from_edges
 from repro.kernels.spmv import blocked_spmv_ref, build_blocked
 from repro.kernels.spmv.ref import coo_spmv_ref
@@ -107,8 +105,18 @@ def test_semiring_laws(sr, vals):
         a, b, c = [v > 0 for v in (a, b, c)]
     comb = {"add": jnp.add, "min": jnp.minimum, "max": jnp.maximum}[sr.combine]
     ident = jnp.asarray(sr.identity, a.dtype)
-    np.testing.assert_allclose(comb(a, comb(b, c)), comb(comb(a, b), c),
-                               rtol=1e-6)
+    lhs, rhs = comb(a, comb(b, c)), comb(comb(a, b), c)
+    if sr.combine == "add":
+        # f32 addition is not associative: each side rounds twice, each
+        # rounding off by at most 2^-24 of the magnitudes summed, so the
+        # sides may differ by 2 * 2^-23 * (|a| + |b| + |c|).  A sum that
+        # lands below the smallest normal is flushed to zero (see above),
+        # off by under 2^-126 for each of the four additions.
+        mags = float(abs(a)) + float(abs(b)) + float(abs(c))
+        bound = 2 * 2.0**-23 * mags + 4 * float(np.finfo(np.float32).tiny)
+        assert abs(float(lhs) - float(rhs)) <= bound
+    else:
+        np.testing.assert_allclose(lhs, rhs, rtol=1e-6)
     np.testing.assert_allclose(comb(a, b), comb(b, a), rtol=1e-6)
     np.testing.assert_allclose(comb(a, ident), a, rtol=1e-6)
 
@@ -160,21 +168,3 @@ def test_coreness_invariant(g):
         mask = members[src] & members[dst]
         np.add.at(sub_deg, src[mask], 1)
         assert (sub_deg[members] >= k).all()
-
-
-@given(
-    st.lists(st.integers(1, 30), min_size=1, max_size=8),
-    st.integers(4, 16),
-)
-def test_packing_preserves_tokens(doc_lens, seq_len):
-    docs = []
-    t = 0
-    for ln in doc_lens:
-        docs.append(np.arange(t, t + ln) % 32749 + 1)
-        t += ln
-    rows, pos = pack_documents(docs, seq_len)
-    flat = rows.reshape(-1)
-    expected = np.concatenate(docs)
-    # every document token appears exactly once, in order, before padding
-    assert (flat[: len(expected)] == expected).all()
-    assert rows.shape[1] == seq_len and pos.shape == rows.shape

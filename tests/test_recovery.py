@@ -11,6 +11,7 @@ to exactly the same bits as one where nobody died.
 import os
 import shutil
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -686,3 +687,40 @@ class TestBatchedStreamRetry:
         assert_identical(base, res, skip=("retries",))
         assert np.array_equal(np.asarray(base.query_supersteps),
                               np.asarray(res.query_supersteps))
+
+
+# ------------------------------------------------------- checkpoint store
+def test_checkpoint_roundtrip(tmp_path):
+    tree = {"a": jnp.arange(6, dtype=jnp.float32).reshape(2, 3),
+            "b": {"c": jnp.asarray(3, jnp.int32)}}
+    save_checkpoint(tmp_path, 5, tree)
+    assert latest_step(tmp_path) == 5
+    like = jax.tree_util.tree_map(jnp.zeros_like, tree)
+    restored, step = restore_checkpoint(tmp_path, like)
+    assert step == 5
+    np.testing.assert_array_equal(restored["a"], tree["a"])
+    assert int(restored["b"]["c"]) == 3
+
+
+def test_checkpoint_atomicity_ignores_partial(tmp_path):
+    tree = {"x": jnp.ones(4)}
+    save_checkpoint(tmp_path, 1, tree)
+    # simulate a crashed mid-write: a .tmp dir and a dir without manifest
+    (tmp_path / "step_00000009.tmp").mkdir()
+    (tmp_path / "step_00000007").mkdir()
+    assert latest_step(tmp_path) == 1
+
+
+def test_checkpoint_manager_async_and_gc(tmp_path):
+    mgr = CheckpointManager(tmp_path, keep=2)
+    for s in (1, 2, 3, 4):
+        mgr.save(s, {"x": jnp.full(3, float(s))}, blocking=(s % 2 == 0))
+    mgr.wait()
+    assert latest_step(tmp_path) == 4
+    steps = sorted(
+        int(d.name.split("_")[1]) for d in tmp_path.iterdir()
+        if d.name.startswith("step_")
+    )
+    assert len(steps) <= 2  # retention
+    restored, step = mgr.restore({"x": jnp.zeros(3)})
+    assert step == 4 and float(restored["x"][0]) == 4.0

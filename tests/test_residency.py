@@ -31,6 +31,7 @@ import numpy as np
 import pytest
 
 import repro
+from conftest import assert_result_parity
 from repro.core import (
     ExecutionPolicy,
     OR_AND,
@@ -58,17 +59,6 @@ def sessions(host):
     the host one can prove it never built a device view."""
     mk = lambda: repro.Graph(host, chunk_size=128, bd=32, bs=32)
     return mk(), mk()
-
-
-def assert_result_parity(rd, rh):
-    assert np.array_equal(np.asarray(rd.values), np.asarray(rh.values))
-    assert int(rd.supersteps) == int(rh.supersteps)
-    for name, a, b in zip(rd.iostats._fields, rd.iostats, rh.iostats):
-        if name == "host_bytes":
-            continue  # the one residency-sensitive (measured) counter
-        assert int(a) == int(b), f"IOStats.{name}: {int(a)} != {int(b)}"
-    assert int(rh.iostats.host_bytes) > 0  # the stream actually shipped
-    assert int(rd.iostats.host_bytes) == 0
 
 
 # ------------------------------------------------------------ parity
